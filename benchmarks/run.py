@@ -11,6 +11,8 @@ import sys
 import time
 import traceback
 
+from repro.runtime import enable_compile_cache
+
 from . import (bench_csa, bench_dse, bench_fig7_energy, bench_fig8_pareto,
                bench_fig9_shmoo, bench_frontend, bench_kernels,
                bench_lattice, bench_multispec, bench_obs, bench_pareto,
@@ -46,6 +48,7 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write rows as a JSON perf artifact")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     selected = MODULES
     if args.only:

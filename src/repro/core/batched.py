@@ -52,7 +52,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from ..runtime import enable_x64
 
 from . import subcircuits as sc
 from .axes import (LatticeConfig, PrecisionPlan, ResolvedAxis, dims_of,

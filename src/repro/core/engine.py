@@ -61,7 +61,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from ..runtime import enable_x64
 
 from . import batched as B
 from . import subcircuits as sc

@@ -16,6 +16,7 @@ are reproduced by construction via :func:`calibrated_tech_for_reference`.
 from __future__ import annotations
 
 import functools
+import operator
 from dataclasses import dataclass, replace
 
 import numpy as np
@@ -303,7 +304,10 @@ def rollup(design: MacroDesign, tech: TechModel,
     breakdown = {"sram_array": a_array, "multmux": a_mult, "adder_tree": a_tree,
                  "shift_adder": a_sa, "ofu": a_ofu, "align": a_align,
                  "drivers": a_drv}
-    area = sum(breakdown.values()) * tech.apr_overhead
+    # Left-to-right f64 adds, as the batched kernel accumulates.  Not sum():
+    # on floats it rounds differently from a plain add chain since 3.12.
+    placed = functools.reduce(operator.add, breakdown.values())
+    area = placed * tech.apr_overhead
 
     # ---- per-cycle energy by mode --------------------------------------------
     # Tree/S&A energies above are per *column*; scale to W columns here.

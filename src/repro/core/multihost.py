@@ -31,7 +31,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from ..runtime import enable_x64
 
 from . import engine as E
 
@@ -79,7 +79,7 @@ def _slice_packed(packed: E.PackedGroup, lo: int, hi: int) -> E.PackedGroup:
     return E.PackedGroup(
         lattices=packed.lattices[lo:hi],
         tables_list=packed.tables_list[lo:hi], csa_i=packed.csa_i,
-        idx=packed.idx,
+        ofu_j=packed.ofu_j, idx=packed.idx,
         operands=(tuple(t[lo:hi] for t in tabs_s), consts_s[lo:hi],
                   e_ofu_s[lo:hi], e_align_s[lo:hi]))
 
@@ -137,7 +137,7 @@ def _run_multiprocess(packed: E.PackedGroup, placement: E.Placement) -> dict:
     padded = E.PackedGroup(
         lattices=packed.lattices + (packed.lattices[0],) * pad,
         tables_list=packed.tables_list + (packed.tables_list[0],) * pad,
-        csa_i=packed.csa_i, idx=packed.idx,
+        csa_i=packed.csa_i, ofu_j=packed.ofu_j, idx=packed.idx,
         operands=(tuple(E.pad_lanes(t, pad) for t in tabs_s),
                   E.pad_lanes(consts_s, pad), E.pad_lanes(e_ofu_s, pad),
                   E.pad_lanes(e_align_s, pad)))

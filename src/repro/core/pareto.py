@@ -74,6 +74,11 @@ def _as_matrix(objs) -> np.ndarray:
     return objs
 
 
+#: Rows per block of the host mask's first (block-local) phase: small blocks
+#: keep its quadratic cost low while still eliminating most dominated rows.
+LOCAL_BLOCK = 128
+
+
 def nondominated_mask(objs, eps: float = PARETO_EPS,
                       chunk: int = 1024) -> np.ndarray:
     """Boolean non-dominated mask over an (n, k) objective matrix
@@ -82,21 +87,28 @@ def nondominated_mask(objs, eps: float = PARETO_EPS,
     is the single dominance predicate :func:`pareto_indices` and the batched
     engine's ``pareto_mask`` both reduce to.
 
-    Runs as a two-phase exact pass: phase 1 tests each block only against
-    itself (a point dominated inside its own block is dominated, full stop —
-    the witness is a real row), phase 2 refines every local survivor against
-    *all* rows.  Because eps-band dominance is not transitive, the refinement
-    deliberately compares against every row, not just other survivors; the
-    resulting mask is identical to the naive all-pairs pass at a fraction of
-    the cost (frontiers are small, so few points reach phase 2)."""
+    Runs as an exact pass in three phases: phase 1 tests each
+    :data:`LOCAL_BLOCK`-row block only against itself (a point dominated inside its own block is
+    dominated, full stop — the witness is a real row), phase 2 tests the
+    local survivors against each other (again a real witness), and phase 3
+    refines what is left against *all* rows.  Because eps-band dominance is
+    not transitive, the refinement deliberately compares against every row,
+    not just other survivors; the resulting mask is identical to the naive
+    all-pairs pass at a fraction of the cost (frontiers are small, so few
+    points reach phase 3)."""
     objs = _as_matrix(objs)
     n = objs.shape[0]
     keep = np.ones(n, dtype=bool)
     if n == 0:
         return keep
-    for start in range(0, n, chunk):
-        blk = objs[start:start + chunk]                 # (c, k)
+    for start in range(0, n, LOCAL_BLOCK):
+        blk = objs[start:start + LOCAL_BLOCK]           # (c, k)
         keep[start:start + blk.shape[0]] = ~chunk_dominated(blk, blk, eps)
+    survivors = np.flatnonzero(keep)
+    peers = objs[survivors]
+    for start in range(0, survivors.size, chunk):
+        idx = survivors[start:start + chunk]
+        keep[idx] = ~chunk_dominated(peers, objs[idx], eps)
     survivors = np.flatnonzero(keep)
     for start in range(0, survivors.size, chunk):
         idx = survivors[start:start + chunk]
@@ -201,7 +213,7 @@ def nondominated_mask_sharded(objs, eps: float = PARETO_EPS,
     mode = E.resolve_sharded_mode(mode)
     fns = _sharded_fns()
     jax, jnp = fns["jax"], fns["jnp"]
-    from jax.experimental import enable_x64
+    from ..runtime import enable_x64
 
     if mode == "jit" and mesh is None:
         # the shared 1-D placement the sharded sweeps use, not an ad-hoc one
@@ -209,14 +221,16 @@ def nondominated_mask_sharded(objs, eps: float = PARETO_EPS,
         mesh = spec_sweep_mesh()
     n_dev = (int(mesh.devices.size) if mesh is not None
              else len(jax.devices()))
-    m = -(-n // n_dev)                               # rows per shard
+    # Every call brings its own row count (each slice of a sweep has one).
+    # Shapes follow a power-of-two bucket of it instead, so each jitted pass
+    # compiles once per bucket, not once per call.
+    n_b = 1 << (n - 1).bit_length()
+    m = -(-n_b // n_dev)                             # rows per shard
     c = int(chunk) if chunk else max(64, min(1024, m))
     m_p = -(-m // c) * c                             # fixed block shapes
-    pad_rows = m_p * n_dev - n
     # +inf padding is inert under the eps band: an inf row never dominates a
     # finite row, and pad verdicts are sliced off before they are read.
-    padded = (np.concatenate([objs, np.full((pad_rows, k), np.inf)])
-              if pad_rows else objs)
+    padded = np.concatenate([objs, np.full((m_p * n_dev - n, k), np.inf)])
     shards = padded.reshape(n_dev, m_p, k)
 
     dominated = np.empty(n_dev * m_p, dtype=bool)
@@ -240,14 +254,14 @@ def nondominated_mask_sharded(objs, eps: float = PARETO_EPS,
         dominated = dominated[:n]
 
         survivors = np.flatnonzero(~dominated)
-        all_rows = jnp.asarray(objs)
+        all_rows = jnp.asarray(padded[:n_b])
         if mode == "jit":
             all_rows = jax.device_put(
                 all_rows, NamedSharding(mesh, PartitionSpec()))  # replicated
         # survivor blocks sized so each device's comparison masks fit its
         # slice of the memory budget
         cr = max(64, pareto_chunk_size(
-            n, k, DEFAULT_PARETO_BUDGET_BYTES // n_dev))
+            n_b, k, DEFAULT_PARETO_BUDGET_BYTES // n_dev))
         stride = n_dev * cr
         for start in range(0, survivors.size, stride):
             idx = survivors[start:start + stride]
@@ -264,20 +278,13 @@ def nondominated_mask_sharded(objs, eps: float = PARETO_EPS,
 
 
 def nondominated_mask_auto(objs, eps: float = PARETO_EPS) -> np.ndarray:
-    """Host mask below the sharding payoff point (or on a single device /
-    without jax), the device-sharded map-reduce above it.  Both produce the
+    """Host mask below the sharding payoff point (or on a single device),
+    the device-sharded map-reduce above it.  Both produce the
     same bits, so callers may switch freely on scale."""
     objs = _as_matrix(objs)
     if objs.shape[0] >= SHARDED_EXTRACT_MIN_POINTS:
-        # Only the jax probe is guarded: a failure *inside* the sharded pass
-        # (device OOM, a sharding regression) must surface, not silently
-        # degrade to the slow host walk.
-        try:
-            import jax
-            n_dev = len(jax.devices())
-        except Exception:
-            n_dev = 1
-        if n_dev > 1:
+        import jax
+        if len(jax.devices()) > 1:
             return nondominated_mask_sharded(objs, eps)
     return nondominated_mask(objs, eps)
 

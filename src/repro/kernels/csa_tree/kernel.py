@@ -9,7 +9,9 @@ at trace time from the row count, exactly like the netlist builder in
 structure.
 
 Layout: operands (H, N) int32 arrive as (H, bn) VMEM blocks (full row dim in
-VMEM — the adder tree is a column-local reduction); the grid tiles N.  The
+VMEM — the adder tree is a column-local reduction); the grid tiles N.  Rows
+and sums stay 2-D (1, bn) tiles: a 1-D int32 block does not match the tiling
+XLA gives a 1-D array on the TPU, so the compiled kernel would be refused.  The
 whole-rows kernel guards its H <= ``CSA_MAX_ROWS`` VMEM residency assumption
 with an explicit ValueError; taller operand stacks go through
 ``csa_tree_tiled_pallas``, which tiles H into (bh, bn) blocks along a
@@ -86,7 +88,7 @@ def _reduce_lanes(lanes: list, use_compressors: bool):
 
 
 def _csa_kernel(x_ref, o_ref, *, h: int, use_compressors: bool):
-    o_ref[...] = _reduce_lanes([x_ref[i, :] for i in range(h)],
+    o_ref[...] = _reduce_lanes([x_ref[i:i + 1, :] for i in range(h)],
                                use_compressors)
 
 
@@ -113,11 +115,11 @@ def csa_tree_pallas(operands: jnp.ndarray, *, use_compressors: bool = True,
         functools.partial(_csa_kernel, h=h, use_compressors=use_compressors),
         grid=(np_ // bn,),
         in_specs=[pl.BlockSpec((h, bn), lambda j: (0, j))],
-        out_specs=pl.BlockSpec((bn,), lambda j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.int32),
+        out_specs=pl.BlockSpec((1, bn), lambda j: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, np_), jnp.int32),
         interpret=interpret,
     )(x)
-    return out[:n]
+    return out[0, :n]
 
 
 # ---------------------------------------------------------------------------
@@ -143,9 +145,9 @@ def _csa_tiled_kernel(x_ref, o_ref, acc_ref, *, bh: int, h_steps: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     if mode == "copy":
-        acc_ref[...] += x_ref[0, :]
+        acc_ref[...] += x_ref[0:1, :]
     else:
-        acc_ref[...] += _reduce_lanes([x_ref[i, :] for i in range(bh)],
+        acc_ref[...] += _reduce_lanes([x_ref[i:i + 1, :] for i in range(bh)],
                                       use_compressors)
 
     @pl.when(t == h_steps - 1)
@@ -173,9 +175,9 @@ def csa_tree_tiled_pallas(operands: jnp.ndarray, *,
                           use_compressors=use_compressors, mode=_mode),
         grid=(np_ // bn, h_steps),
         in_specs=[pl.BlockSpec((bh, bn), lambda j, t: (t, j))],
-        out_specs=pl.BlockSpec((bn,), lambda j, t: (j,)),
-        out_shape=jax.ShapeDtypeStruct((np_,), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((bn,), jnp.int32)],
+        out_specs=pl.BlockSpec((1, bn), lambda j, t: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((1, np_), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, bn), jnp.int32)],
         interpret=interpret,
     )(x)
-    return out[:n]
+    return out[0, :n]

@@ -10,7 +10,9 @@ no MXU analogue; the TPU-native mapping keeps the *roles*:
   OFU / alignment epilogue      ->  fused per-channel dequant on final k step
 
 Blocked matmul with grid (M/bm, N/bn, K/bk), k innermost (sequential on TPU)
-so the int32 accumulator lives in a VMEM scratch across k steps.  Block shapes
+so the int32 accumulator lives in a VMEM scratch across k steps.  The
+dequant scales ride as (M, 1) and (1, N) columns: a 1-D f32 block does not
+match the tiling XLA gives a 1-D array on the TPU.  Block shapes
 default to MXU-aligned 128 multiples; int8 operands, int32 accumulate
 (``preferred_element_type``), bf16/f32 output after the epilogue.
 
@@ -54,7 +56,7 @@ def _mac_kernel(a_ref, w_ref, asc_ref, wsc_ref, o_ref, acc_ref, *, k_steps: int,
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _epilogue():
         # OFU-equivalent: per-row activation scale x per-column weight scale.
-        scale = asc_ref[...].reshape(-1, 1) * wsc_ref[...].reshape(1, -1)
+        scale = asc_ref[...] * wsc_ref[...]
         o_ref[...] = (acc_ref[...].astype(jnp.float32) * scale).astype(out_dtype)
 
 
@@ -101,8 +103,8 @@ def dcim_matmul_pallas(a_q: jnp.ndarray, w_q: jnp.ndarray,
 
     a_p = _pad_to(a_q, (bm, bk))
     w_p = _pad_to(w_q, (bk, bn))
-    asc = _pad_to(a_scale, (bm,))
-    wsc = _pad_to(w_scale, (bn,))
+    asc = _pad_to(a_scale[:, None], (bm, 1))
+    wsc = _pad_to(w_scale[None, :], (1, bn))
     mp, kp = a_p.shape
     _, np_ = w_p.shape
     grid = (mp // bm, np_ // bn, kp // bk)
@@ -113,8 +115,8 @@ def dcim_matmul_pallas(a_q: jnp.ndarray, w_q: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, t: (i, t)),
             pl.BlockSpec((bk, bn), lambda i, j, t: (t, j)),
-            pl.BlockSpec((bm,), lambda i, j, t: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, t: (j,)),
+            pl.BlockSpec((bm, 1), lambda i, j, t: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, t: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
@@ -215,7 +217,7 @@ def _mac_pipelined_kernel(a_hbm, w_hbm, asc_ref, wsc_ref, o_ref, a_buf,
                           k_steps: int, depth: int, out_dtype, mode: str):
     acc = _pipelined_mac_body(a_hbm, w_hbm, a_buf, w_buf, sems, bm=bm, bn=bn,
                               bk=bk, k_steps=k_steps, depth=depth, mode=mode)
-    scale = asc_ref[...].reshape(-1, 1) * wsc_ref[...].reshape(1, -1)
+    scale = asc_ref[...] * wsc_ref[...]
     o_ref[...] = (acc.astype(jnp.float32) * scale).astype(out_dtype)
 
 
@@ -253,8 +255,8 @@ def dcim_matmul_pipelined_pallas(a_q: jnp.ndarray, w_q: jnp.ndarray,
     w_scale = jnp.broadcast_to(jnp.asarray(w_scale, jnp.float32), (n,))
     a_p = _pad_to(a_q, (bm, bk))
     w_p = _pad_to(w_q, (bk, bn))
-    asc = _pad_to(a_scale, (bm,))
-    wsc = _pad_to(w_scale, (bn,))
+    asc = _pad_to(a_scale[:, None], (bm, 1))
+    wsc = _pad_to(w_scale[None, :], (1, bn))
     mp, kp = a_p.shape
     _, np_ = w_p.shape
     grid = (mp // bm, np_ // bn)
@@ -264,10 +266,10 @@ def dcim_matmul_pipelined_pallas(a_q: jnp.ndarray, w_q: jnp.ndarray,
                           mode=_mode),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
@@ -298,8 +300,8 @@ def dcim_matmul_int_pipelined_pallas(a_q: jnp.ndarray, w_q: jnp.ndarray,
                           k_steps=kp // bk, depth=depth, mode=_mode),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),

@@ -36,7 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _chunk_states(a, b, h_in, *, bt: int):
-    """States of one (bt, bd) chunk given the incoming carry: log-depth
+    """States of one (bt, bd) chunk given the (1, bd) incoming carry: log-depth
     associative doubling — (A, B)_t composes prefix products,
     shift-and-combine doubles span — then states_t = A_t * h_in + B_t."""
     A, B = a, b
@@ -49,7 +49,7 @@ def _chunk_states(a, b, h_in, *, bt: int):
         B = A * B_shift + B
         A = A * A_shift
         span *= 2
-    return A * h_in[None, :] + B
+    return A * h_in + B
 
 
 def _scan_chunk_kernel(a_ref, b_ref, h0_ref, o_ref, hfin_ref, carry_ref,
@@ -63,7 +63,7 @@ def _scan_chunk_kernel(a_ref, b_ref, h0_ref, o_ref, hfin_ref, carry_ref,
     # (bt, bd) chunk; prefix-inclusive states from the carried state.
     states = _chunk_states(a_ref[...], b_ref[...], carry_ref[...], bt=bt)
     o_ref[...] = states
-    carry_ref[...] = states[-1, :]
+    carry_ref[...] = states[bt - 1:bt, :]
 
     @pl.when(t == t_steps - 1)
     def _final():
@@ -94,21 +94,21 @@ def ssm_scan_pallas(a: jnp.ndarray, b: jnp.ndarray, h0: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bt, bd), lambda j, t: (t, j)),
             pl.BlockSpec((bt, bd), lambda j, t: (t, j)),
-            pl.BlockSpec((bd,), lambda j, t: (j,)),
+            pl.BlockSpec((1, bd), lambda j, t: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bt, bd), lambda j, t: (t, j)),
-            pl.BlockSpec((bd,), lambda j, t: (j,)),
+            pl.BlockSpec((1, bd), lambda j, t: (0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((tp, dp), a.dtype),
-            jax.ShapeDtypeStruct((dp,), a.dtype),
+            jax.ShapeDtypeStruct((1, dp), a.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((bd,), a.dtype)],
+        scratch_shapes=[pltpu.VMEM((1, bd), a.dtype)],
         interpret=interpret,
-    )(a_p, b_p, h0_p)
+    )(a_p, b_p, h0_p[None, :])
     out_states = states[:t_len, :d]
-    final = out_states[-1, :] if pt else hfin[:d]
+    final = out_states[-1, :] if pt else hfin[0, :d]
     return out_states, final
 
 
@@ -159,7 +159,7 @@ def _scan_pipelined_kernel(a_hbm, b_hbm, h0_ref, o_hbm, hfin_ref, a_buf,
         else:
             src = slot if stream else 0
             states = _chunk_states(a_buf[src], b_buf[src], h, bt=bt)
-            h = states[-1, :]
+            h = states[bt - 1:bt, :]
             if stream:
                 o_buf[slot] = states
         if stream:
@@ -201,17 +201,17 @@ def ssm_scan_pipelined_pallas(a: jnp.ndarray, b: jnp.ndarray,
                           depth=depth, mode=_mode),
         grid=(dp // bd,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((bd,), lambda j: (j,)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, bd), lambda j: (0, j)),
         ],
         out_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec((bd,), lambda j: (j,)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, bd), lambda j: (0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((tp, dp), a.dtype),
-            jax.ShapeDtypeStruct((dp,), a.dtype),
+            jax.ShapeDtypeStruct((1, dp), a.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((depth, bt, bd), a.dtype),
@@ -221,7 +221,7 @@ def ssm_scan_pipelined_pallas(a: jnp.ndarray, b: jnp.ndarray,
             pltpu.SemaphoreType.DMA((depth,)),
         ],
         interpret=interpret,
-    )(a_p, b_p, h0_p)
+    )(a_p, b_p, h0_p[None, :])
     out_states = states[:t_len, :d]
-    final = out_states[-1, :] if pt else hfin[:d]
+    final = out_states[-1, :] if pt else hfin[0, :d]
     return out_states, final

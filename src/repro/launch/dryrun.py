@@ -1,5 +1,8 @@
 import os
+# A CPU-only tool: 512 placeholder host devices, and never the chip (the
+# --all children inherit both settings through the environment).
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes with 512 placeholder host devices — proving the sharding

@@ -8,21 +8,15 @@ pure data parallelism across DCN.
 
 from __future__ import annotations
 
+import math
+
 import jax
 
 
 def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """``jax.make_mesh`` with Auto axis types where the runtime has them.
-
-    Capability is detected with ``hasattr`` — never a version pin — so the
-    same call works on the pinned jax 0.4.37 (whose ``make_mesh`` takes no
-    ``axis_types`` and whose ``jax.sharding`` has no ``AxisType``) and
-    un-gates automatically on newer jax."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto-sharded."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -33,11 +27,10 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_host_mesh(shape: tuple[int, ...] = (1, 1),
                    axes: tuple[str, ...] = ("data", "model")):
-    """Small mesh over whatever devices exist (tests / local runs)."""
-    n = 1
-    for s in shape:
-        n *= s
-    avail = len(jax.devices())
+    """Small mesh over the devices of this host (tests / local runs).
+    Raises when ``shape`` asks for more devices than exist."""
+    n, avail = math.prod(shape), len(jax.devices())
     if n > avail:
-        shape = (1,) * (len(shape) - 1) + (avail,)
+        raise ValueError(f"mesh {shape} needs {n} devices; "
+                         f"this runtime has {avail}")
     return _mesh(shape, axes)

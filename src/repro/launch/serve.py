@@ -42,13 +42,14 @@ from ..configs import get_config, smoke_config
 from ..models import get_model
 from ..parallel.logical import split_logical
 from ..parallel.sharding import rules_for_mesh
+from ..runtime import enable_compile_cache
 from ..serve import make_decode_step, make_prefill
 from ..serve.config import serve_config_from_args
 from .mesh import make_host_mesh
 from .train import parse_mesh
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -104,7 +105,8 @@ def main() -> None:
                          "profile_kernels.py --json: its measured pipeline "
                          "efficiency derates the serving roofline "
                          "(kernel_fraction)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     dcim = serve_config_from_args(args)

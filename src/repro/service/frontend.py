@@ -284,7 +284,7 @@ class ServiceFrontend:
                 raise RuntimeError(
                     f"request shedded ({resp.reason}, queue_depth="
                     f"{resp.queue_depth}); retry with backoff or raise "
-                    "max_depth")
+                    f"max_depth. {resp.detail}".rstrip())
             out.append(resp)
         return out
 

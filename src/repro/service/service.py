@@ -56,7 +56,8 @@ from ..core import engine as E
 from ..core import subcircuits as sc
 from ..core.axes import SLICEABLE_AXES, LatticeConfig, seed_config
 from ..core.macro import MacroSpec, calibrated_tech_for_reference
-from ..core.pareto import merged_pareto_indices, nondominated_mask_auto
+from ..core.pareto import (merged_pareto_indices, nondominated_mask,
+                           nondominated_mask_auto)
 from ..core.searcher import SearchResult
 from ..core.tech import TechModel
 from ..obs import install_engine_hooks, tracer
@@ -311,6 +312,19 @@ class SynthesisService:
                 if on_partial is not None:
                     on_partial(d, res)
 
+        # A cold sweep joins its mode's fused pass; a sweep with cached
+        # slice frontiers is merged incrementally on its own.
+        incremental: dict[int, tuple] = {}
+        for i in sweep_misses:
+            tech, _res, mode, config = eff[i]
+            with (tracer.activate(ctxs[i]) if ctxs[i] is not None
+                  else _NULL_CTX):
+                slices = self._cached_slices(reqs[i].spec, tech, config)
+            if slices is None:
+                miss_by_mode.setdefault(mode, []).append(i)
+            else:
+                incremental[i] = slices
+
         for mode, members in miss_by_mode.items():
             self.stats.misses += len(members)
             pass_ref: dict = {}
@@ -320,14 +334,15 @@ class SynthesisService:
                                  finish(_m[slot], res, _p),
                              pass_ref=pass_ref)
 
-        for i in sweep_misses:
+        for i, (lattice, slices) in incremental.items():
             self.stats.misses += 1
             tech, _res, _mode, config = eff[i]
             with (tracer.activate(ctxs[i]) if ctxs[i] is not None
                   else _NULL_CTX):
                 with tracer.span("service.sweep",
                                  tags={"kind": "sweep"}):
-                    res = self._serve_sweep(reqs[i].spec, tech, config)
+                    res = self._incremental_sweep(reqs[i].spec, tech,
+                                                  config, lattice, slices)
             finish(i, res)
         return out
 
@@ -435,26 +450,35 @@ class SynthesisService:
             plan = E.plan_for(lattices, tables, placement=placement)
             evals = E.execute(plan)          # engine.execute span: obs hooks
             self.stats.fused_passes += 1
+            # A sweep lane's frontier and slice records are extracted where
+            # the pass ran: the host walk after a one-device pass, the
+            # device-sharded mask (above its payoff point) after a sharded
+            # one.
+            mask = (nondominated_mask if placement.n_dev == 1
+                    else nondominated_mask_auto)
             for slot, (lat, tab, T) in enumerate(evals):
+                tech, resolution, _, config = eff[slot]
                 with tracer.span("engine.extract", tags={"slot": slot}):
-                    res = B._alg1_replay(lat, tab, T, eff[slot][1])
+                    if requests[slot].kind == "sweep":
+                        res = self._cold_sweep(
+                            B.BatchedSweep(lattice=lat, tables=tab, ppa=T,
+                                           extract_mask=mask),
+                            tech, config)
+                    else:
+                        res = B._alg1_replay(lat, tab, T, resolution)
                 on_result(slot, res)
 
     # -- exhaustive sweeps: slice caching + incremental re-synthesis ---------
 
-    def _serve_sweep(self, spec: MacroSpec, tech: TechModel,
-                     config: LatticeConfig) -> SearchResult:
-        """One exhaustive-sweep miss.
+    def _cached_slices(self, spec: MacroSpec, tech: TechModel,
+                       config: LatticeConfig):
+        """Probe the per-axis-value *slice* caches of one sweep miss.
 
-        Probes the per-axis-value *slice* caches first: if some sliceable
-        axis has cached frontiers for a subset of its values (the shape left
-        behind by a scoped tech recalibration or a single-axis growth — see
-        :mod:`repro.service.keys`), only the sublattice of the missing
-        values is evaluated and its slice frontiers are merged with the
-        cached ones (:func:`repro.core.pareto.merged_pareto_indices`), never
-        re-rolling the full axis product.  A fully cold sweep evaluates the
-        whole lattice once and leaves slice records behind for every
-        sliceable axis, so the *next* single-axis change is incremental."""
+        Returns ``None`` when no sliceable axis has a cached frontier (the
+        sweep is cold and joins the fused pass), else ``(lattice, (axis,
+        cached, slice_keys))`` for the axis with the most cached values —
+        the shape left behind by a scoped tech recalibration or a
+        single-axis growth (see :mod:`repro.service.keys`)."""
         lattice = B.DesignLattice.enumerate(spec, config=config)
         best: tuple[str, dict[int, SearchResult], list[str]] | None = None
         for axis in SLICEABLE_AXES:
@@ -470,11 +494,16 @@ class SynthesisService:
                     cached[v] = rec
             if cached and (best is None or len(cached) > len(best[1])):
                 best = (axis, cached, skeys)
+        return None if best is None else (lattice, best)
 
-        if best is None:
-            return self._cold_sweep(spec, tech, config, lattice)
-
-        axis, cached, skeys = best
+    def _incremental_sweep(self, spec: MacroSpec, tech: TechModel,
+                           config: LatticeConfig, lattice: B.DesignLattice,
+                           slices: tuple) -> SearchResult:
+        """One sweep miss with cached slices: only the sublattice of the
+        missing axis values is evaluated, and its slice frontiers are merged
+        with the cached ones (:func:`repro.core.pareto.
+        merged_pareto_indices`), never re-rolling the full axis product."""
+        axis, cached, skeys = slices
         self.stats.incremental_passes += 1
         self.stats.slice_hits += len(cached)
         missing = [v for v in range(lattice.axis(axis).size)
@@ -496,10 +525,12 @@ class SynthesisService:
                    for v in range(lattice.axis(axis).size)]
         return _merge_slice_results(lattice, records)
 
-    def _cold_sweep(self, spec: MacroSpec, tech: TechModel,
-                    config: LatticeConfig,
-                    lattice: B.DesignLattice) -> SearchResult:
-        sweep = B.design_space_sweep(spec, tech, config=config)
+    def _cold_sweep(self, sweep: B.BatchedSweep, tech: TechModel,
+                    config: LatticeConfig) -> SearchResult:
+        """The tail of a cold sweep lane: leaves slice records behind for
+        every sliceable axis, so the *next* single-axis change is
+        incremental, and returns the full-sweep result."""
+        spec = sweep.lattice.spec
         for axis in SLICEABLE_AXES:
             ax = sweep.lattice.axis(axis)
             if ax is None:
@@ -532,8 +563,8 @@ def _extract_sweep_indices(sweep: B.BatchedSweep,
     if cand.size == 0:
         return []
     objs = sweep.objectives()[cand]
-    return [int(cand[j])
-            for j in E.extract_frontier(objs, nondominated_mask_auto)]
+    return [int(cand[j]) for j in E.extract_frontier(
+        objs, sweep.extract_mask or nondominated_mask_auto)]
 
 
 def _slice_record(sweep: B.BatchedSweep, sel: np.ndarray) -> SearchResult:

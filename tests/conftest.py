@@ -1,23 +1,3 @@
-import importlib.util
-import pathlib
-
-
-def _install_hypothesis_fallback() -> None:
-    """The runtime image may lack hypothesis (CI installs the real one from
-    requirements-dev.txt).  Register the deterministic fallback before test
-    modules import it, so collection never fails offline."""
-    if importlib.util.find_spec("hypothesis") is not None:
-        return
-    path = pathlib.Path(__file__).with_name("_hypothesis_fallback.py")
-    spec = importlib.util.spec_from_file_location("_hypothesis_fallback", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    mod.install()
-
-
-_install_hypothesis_fallback()
-
-
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-device subprocess drills (seconds to minutes)")

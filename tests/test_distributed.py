@@ -53,8 +53,7 @@ def test_sharded_train_step_8dev():
 
         cfg = smoke_config('llama3.2-3b')
         api = get_model(cfg)
-        mk = ({'axis_types': (jax.sharding.AxisType.Auto,) * 2}
-              if hasattr(jax.sharding, 'AxisType') else {})
+        mk = {'axis_types': (jax.sharding.AxisType.Auto,) * 2}
         mesh = jax.make_mesh((2, 4), ('data', 'model'), **mk)
         rules = rules_for_mesh(mesh)
         ltree = api.init_params(jax.random.PRNGKey(0))
@@ -94,8 +93,7 @@ def test_compressed_pod_allreduce_matches_dense():
         from jax.experimental.shard_map import shard_map
         from repro.optim.compression import compressed_psum_tree, ef_state_init
 
-        mk = ({'axis_types': (jax.sharding.AxisType.Auto,) * 2}
-              if hasattr(jax.sharding, 'AxisType') else {})
+        mk = {'axis_types': (jax.sharding.AxisType.Auto,) * 2}
         mesh = jax.make_mesh((2, 4), ('pod', 'data'), **mk)
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(size=(8, 64)) * 0.01, jnp.float32)
@@ -132,8 +130,7 @@ def test_elastic_remesh_roundtrip(tmp_path):
                 'b': jnp.ones((8,))}}
         spec = {{'w': P('data', 'model'), 'b': P('data')}}
 
-        mk = {{'axis_types': (jax.sharding.AxisType.Auto,) * 2}} \\
-            if hasattr(jax.sharding, 'AxisType') else {{}}
+        mk = {{'axis_types': (jax.sharding.AxisType.Auto,) * 2}}
         m8 = jax.make_mesh((4, 2), ('data', 'model'), **mk)
         placed = reshard_tree(tree, m8, spec)
         mgr = CheckpointManager(r'{tmp_path}', keep=2)

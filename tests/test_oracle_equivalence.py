@@ -41,8 +41,8 @@ from hypothesis import strategies as st
 from repro.core import calibrated_tech_for_reference, mso_search
 from repro.core.macro import MacroSpec
 from repro.core.multispec import mso_search_many, scenario_specs
-from repro.core.pareto import (PARETO_EPS, nondominated_mask,
-                               nondominated_mask_sharded)
+from repro.core.pareto import (LOCAL_BLOCK, PARETO_EPS, dominates,
+                               nondominated_mask, nondominated_mask_sharded)
 from repro.core.shardspec import (mso_search_many_sharded, resolve_mode,
                                   spec_variants)
 
@@ -333,6 +333,14 @@ class TestShardedParetoExtraction:
         shard = nondominated_mask_sharded(objs, mode=mode)
         assert np.array_equal(host, shard)
 
+    @pytest.mark.parametrize("seed", [0, 1])
+    def test_host_mask_matches_all_pairs(self, seed):
+        """The host mask's block-local, survivor and full-refinement phases
+        give the naive all-pairs verdicts over several LOCAL_BLOCK blocks."""
+        objs = _extraction_points(seed, 3 * LOCAL_BLOCK + 5, 3)
+        naive = [not any(dominates(o, p) for o in objs) for p in objs]
+        assert nondominated_mask(objs).tolist() == naive
+
     @settings(max_examples=4, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=10**6),
            chunk=st.sampled_from([64, 100, 1024]))
@@ -343,6 +351,21 @@ class TestShardedParetoExtraction:
         for mode in ("jit", "pmap"):
             got = nondominated_mask_sharded(objs, chunk=chunk, mode=mode)
             assert np.array_equal(host, got)
+
+    def test_row_counts_in_one_bucket_share_compiled_passes(self):
+        """Row counts within one power-of-two bucket reuse the compiled
+        passes: a sweep's slices each bring their own count, and a compile
+        per call costs seconds on a TPU."""
+        from repro.core.pareto import _sharded_fns
+        fns = _sharded_fns()
+        nondominated_mask_sharded(_extraction_points(1, 2100, 3), mode="jit")
+        sizes = (fns["local"]._cache_size(), fns["refine"]._cache_size())
+        for n in (2200, 3000, 4096):
+            objs = _extraction_points(n, n, 3)
+            assert np.array_equal(nondominated_mask_sharded(objs, mode="jit"),
+                                  nondominated_mask(objs))
+        assert (fns["local"]._cache_size(),
+                fns["refine"]._cache_size()) == sizes
 
     def test_frontier_order_identical_through_shared_tail(self, tech):
         """The engine's frontier tail orders identically through the host and
@@ -567,6 +590,25 @@ class TestIncrementalSweepEquivalence:
         from repro.service import FrontierCache, SynthesisService
         return SynthesisService(cache=FrontierCache(store_dir=tmp_path),
                                 config=config)
+
+    def test_cold_sweeps_fuse_into_one_pass(self, tmp_path, tech):
+        """A burst of cold sweeps runs as one fused engine pass, and each
+        lane's frontier is that spec's standalone exhaustive sweep's."""
+        from repro.core import subcircuits as sc
+        from repro.core.axes import LatticeConfig
+        from repro.core.batched import design_space_sweep
+        from repro.service import SynthesisRequest
+        cfg = LatticeConfig(memcells=(sc.MemCellKind.SRAM_6T,))
+        svc = self._service(tmp_path, cfg)
+        specs = spec_variants(3, seed=5)
+        out = svc.serve([SynthesisRequest(spec=s, tech=tech, kind="sweep")
+                         for s in specs])
+        assert svc.stats.fused_passes == 1 and svc.stats.misses == 3
+        for s, r in zip(specs, out):
+            sweep = design_space_sweep(s, tech, config=cfg)
+            assert [p.design.name() for p in r.result.frontier] == \
+                [sweep.lattice.design_at(i).name()
+                 for i in sweep.frontier_indices()]
 
     def test_scoped_recalibration_merges_bit_identical(self, tmp_path, tech):
         import dataclasses

@@ -50,8 +50,7 @@ class TestWalkerOnCompiledHLO:
             from jax import lax
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.roofline.hlo_parse import analyze_hlo_text
-            mk = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-                  if hasattr(jax.sharding, "AxisType") else {})
+            mk = {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
             mesh = jax.make_mesh((2, 4), ("data", "model"), **mk)
             def body(x, w):
                 return jnp.tanh(x @ w), 0
@@ -82,8 +81,7 @@ class TestWalkerOnCompiledHLO:
             import json, jax, jax.numpy as jnp
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.roofline.hlo_parse import analyze_hlo_text
-            mk = ({"axis_types": (jax.sharding.AxisType.Auto,)}
-                  if hasattr(jax.sharding, "AxisType") else {})
+            mk = {"axis_types": (jax.sharding.AxisType.Auto,)}
             mesh = jax.make_mesh((8,), ("data",), **mk)
             def f(x):
                 return x.sum(axis=0)   # cross-device reduction

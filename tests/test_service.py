@@ -349,6 +349,26 @@ class TestMultiHostStrategy:
         assert mesh.devices.size == len(jax.devices())
         assert mesh.devices.shape[0] == jax.process_count()
 
+    def test_host_slice_unpacks(self, tech):
+        """One host's lane slice of a packed group carries every
+        PackedGroup field, so the shared numpy tail runs on it and gives
+        that slice's lanes of the whole group."""
+        import jax
+        import numpy as np
+        from repro.core.multihost import _slice_packed
+        p = engine.plan(spec_variants(4, seed=61), tech)
+        packed = engine.pack_group(p.lattices, p.tables)
+        out = engine.STRATEGIES["vmap"].run(packed, engine.place("vmap"))
+        whole = engine.unpack_group(packed, out)
+        part = engine.unpack_group(_slice_packed(packed, 1, 3),
+                                   jax.tree.map(lambda a: a[1:3], out))
+        assert len(part) == 2
+        for got, ref in zip(part, whole[1:3]):
+            assert got.lattice is ref.lattice
+            for f in ("area", "latency", "fmax", "tops_mm2"):
+                assert np.array_equal(getattr(got, f), getattr(ref, f),
+                                      equal_nan=True)
+
     def test_multihost_matches_unsharded(self, tech):
         if not engine.STRATEGIES["multihost"].available():
             pytest.skip("multihost strategy unavailable on this runtime")
