@@ -48,7 +48,13 @@ not a fifth reimplementation of the pipeline:
 Execution is observable: :func:`add_execute_hook` registers a callback fired
 once per :func:`execute` call with the plan being run.  The online synthesis
 service (:mod:`repro.service`) counts engine executions through this hook to
-prove cache hits and request coalescing never re-enter the engine.
+prove cache hits and request coalescing never re-enter the engine.  With
+tracing on (:mod:`repro.obs`), :func:`execute` records one ``engine.execute``
+span per call with a child per group for each of its steps — ``execute.pack``
+(host operand packing), ``execute.wait`` (device compute and queueing),
+``execute.fetch`` (device-to-host copy) and ``execute.unpack`` (the numpy
+tail); the rest of ``engine.execute`` is operand upload and launch.
+:func:`extract_frontier` records ``extract.mask`` around each survivor mask.
 """
 
 from __future__ import annotations
@@ -61,6 +67,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from ..obs import tracer
 from ..runtime import enable_x64
 
 from . import batched as B
@@ -70,6 +77,8 @@ from .macro import MacroSpec
 # Chunk sizing lives with the shared Pareto predicate; re-exported here for
 # strategies sizing frontier chunks against the accelerator budget.
 from .pareto import pareto_chunk_size, pareto_indices  # noqa: F401
+from .pareto import (SHARDED_EXTRACT_MIN_POINTS, auto_mask_shards,
+                     nondominated_mask, nondominated_mask_auto)
 from .tech import TechModel
 
 # ---------------------------------------------------------------------------
@@ -285,6 +294,20 @@ def place(mode: str = "auto", mesh=None, *, sharded: bool = False
 # ---------------------------------------------------------------------------
 
 
+def fetch_outputs(out) -> dict:
+    """Wait for a strategy's device outputs (``execute.wait``: device
+    compute and queueing), then copy each leaf to host numpy
+    (``execute.fetch``, tagged with the leaf count and bytes copied)."""
+    with tracer.span("execute.wait"):
+        out = jax.block_until_ready(out)
+    with tracer.span("execute.fetch") as span:
+        if span:
+            leaves = jax.tree.leaves(out)
+            span.set_tag("arrays", len(leaves))
+            span.set_tag("bytes", sum(int(a.nbytes) for a in leaves))
+        return jax.tree.map(np.asarray, out)
+
+
 def _run_jit(packed: PackedGroup, placement: Placement) -> dict:
     """Single-spec unbatched launch — the :mod:`repro.core.batched` path."""
     if len(packed) != 1:
@@ -297,7 +320,7 @@ def _run_jit(packed: PackedGroup, placement: Placement) -> dict:
                              jnp.asarray(consts_s[0]),
                              jnp.asarray(e_ofu_s[0]),
                              jnp.asarray(e_align_s[0]))
-        out = jax.tree.map(np.asarray, out)
+        out = fetch_outputs(out)
     return jax.tree.map(lambda a: a[None], out)
 
 
@@ -309,7 +332,7 @@ def _run_vmap(packed: PackedGroup, placement: Placement) -> dict:
         out = _eval_kernel_many(idx, tuple(jnp.asarray(t) for t in tabs_s),
                                 jnp.asarray(consts_s), jnp.asarray(e_ofu_s),
                                 jnp.asarray(e_align_s))
-        out = jax.tree.map(np.asarray, out)
+        out = fetch_outputs(out)
     return out
 
 
@@ -348,7 +371,7 @@ def _run_sharded_jit(packed: PackedGroup, placement: Placement) -> dict:
         out = _eval_kernel_many(idx, tuple(put(t, True) for t in tabs_p),
                                 put(consts_p, True), put(e_ofu_p, True),
                                 put(e_align_p, True))
-        out = jax.tree.map(np.asarray, out)
+        out = fetch_outputs(out)
     if pad:
         out = jax.tree.map(lambda a: a[:len(packed)], out)
     return out
@@ -374,8 +397,8 @@ def _run_pmap(packed: PackedGroup, placement: Placement) -> dict:
         # unfold (devices, specs/device) -> specs on the host copy: a numpy
         # view, and no further jax dispatch on this branch
         out = jax.tree.map(
-            lambda a: np.asarray(a).reshape((n_dev * per_dev,) + a.shape[2:]),
-            out)
+            lambda a: a.reshape((n_dev * per_dev,) + a.shape[2:]),
+            fetch_outputs(out))
     if pad:
         out = jax.tree.map(lambda a: a[:len(packed)], out)
     return out
@@ -497,12 +520,19 @@ def execute(p: ExecutionPlan
     t0 = time.perf_counter()
     strategy = STRATEGIES[p.placement.mode]
     out: list = [None] * len(p)
-    for members in p.groups:
-        packed = pack_group([p.lattices[i] for i in members],
-                            [p.tables[i] for i in members])
-        ppas = unpack_group(packed, strategy.run(packed, p.placement))
-        for i, ppa in zip(members, ppas):
-            out[i] = (p.lattices[i], p.tables[i], ppa)
+    with tracer.span("engine.execute",
+                     tags={"mode": p.placement.mode, "n_specs": len(p),
+                           "n_groups": len(p.groups),
+                           "n_dev": p.placement.n_dev}):
+        for members in p.groups:
+            with tracer.span("execute.pack"):
+                packed = pack_group([p.lattices[i] for i in members],
+                                    [p.tables[i] for i in members])
+            raw = strategy.run(packed, p.placement)
+            with tracer.span("execute.unpack"):
+                ppas = unpack_group(packed, raw)
+            for i, ppa in zip(members, ppas):
+                out[i] = (p.lattices[i], p.tables[i], ppa)
     elapsed = time.perf_counter() - t0
     for hook in tuple(_LATENCY_HOOKS):
         hook(p, elapsed)
@@ -514,6 +544,22 @@ def execute(p: ExecutionPlan
 # ---------------------------------------------------------------------------
 
 
+def _mask_site(mask_fn, n_rows: int) -> str:
+    """Where ``mask_fn`` computes an ``n_rows``-row mask: ``"host"`` (the
+    host walk) or ``"devices"`` (the sharded map-reduce, or the one-device
+    chunked pass).  The two dispatchers go to the devices at the sharding
+    payoff point."""
+    from . import shardspec
+    fn = getattr(mask_fn, "func", mask_fn)       # unwrap functools.partial
+    if fn is nondominated_mask:
+        return "host"
+    if fn is nondominated_mask_auto:
+        return "devices" if auto_mask_shards(n_rows) else "host"
+    if fn is shardspec._sharded_extract:
+        return "devices" if n_rows >= SHARDED_EXTRACT_MIN_POINTS else "host"
+    return "devices"
+
+
 def extract_frontier(objs, mask_fn: Callable[[np.ndarray], np.ndarray]
                      ) -> list[int]:
     """The numpy frontier tail every sweep shares: a survivor mask from
@@ -522,9 +568,15 @@ def extract_frontier(objs, mask_fn: Callable[[np.ndarray], np.ndarray]
     device-sharded :func:`repro.core.pareto.nondominated_mask_sharded` — all
     bit-identical by construction), then the exact dedup/order pass of
     :func:`repro.core.pareto.pareto_indices` on the survivors.  Returns
-    indices into ``objs`` sorted by objective tuple."""
+    indices into ``objs`` sorted by objective tuple.  The mask runs inside
+    an ``extract.mask`` span tagged with its ``rows`` and ``where`` it ran
+    (``host`` or ``devices``)."""
     objs = np.asarray(objs, dtype=np.float64)
-    mask = np.asarray(mask_fn(objs)).astype(bool)
+    with tracer.span("extract.mask") as span:
+        if span:
+            span.set_tag("rows", len(objs))
+            span.set_tag("where", _mask_site(mask_fn, len(objs)))
+        mask = np.asarray(mask_fn(objs)).astype(bool)
     survivors = np.flatnonzero(mask)
     order = pareto_indices([tuple(o) for o in objs[mask]])
     return [int(survivors[i]) for i in order]

@@ -105,7 +105,7 @@ def _run_single_controller(packed: E.PackedGroup,
         out = E._eval_kernel_many(idx, tuple(put(t, True) for t in tabs_p),
                                   put(consts_p, True), put(e_ofu_p, True),
                                   put(e_align_p, True))
-        out = jax.tree.map(np.asarray, out)
+        out = E.fetch_outputs(out)
     if pad:
         out = jax.tree.map(lambda a: a[:len(packed)], out)
     return out

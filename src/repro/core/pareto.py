@@ -277,15 +277,23 @@ def nondominated_mask_sharded(objs, eps: float = PARETO_EPS,
     return ~dominated
 
 
+def auto_mask_shards(n_rows: int) -> bool:
+    """Whether :func:`nondominated_mask_auto` takes the device-sharded pass
+    for ``n_rows`` rows: at or above the payoff point, on several
+    devices."""
+    if n_rows < SHARDED_EXTRACT_MIN_POINTS:
+        return False
+    import jax
+    return len(jax.devices()) > 1
+
+
 def nondominated_mask_auto(objs, eps: float = PARETO_EPS) -> np.ndarray:
     """Host mask below the sharding payoff point (or on a single device),
     the device-sharded map-reduce above it.  Both produce the
     same bits, so callers may switch freely on scale."""
     objs = _as_matrix(objs)
-    if objs.shape[0] >= SHARDED_EXTRACT_MIN_POINTS:
-        import jax
-        if len(jax.devices()) > 1:
-            return nondominated_mask_sharded(objs, eps)
+    if auto_mask_shards(objs.shape[0]):
+        return nondominated_mask_sharded(objs, eps)
     return nondominated_mask(objs, eps)
 
 

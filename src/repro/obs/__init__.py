@@ -5,8 +5,11 @@ One subsystem answers "why was this response slow?" end to end:
 - :mod:`repro.obs.tracing` — request-scoped spans (trace_id/span_id via
   contextvars, thread-safe across the frontend's scheduler thread) opened
   at frontend admission, per cache tier probed, around the fused engine
-  pass (via the engine's own execute/latency hooks — strategies stay
-  untouched), and at kernel dispatch.
+  pass and each of its phases, and at kernel dispatch; mirrored into the
+  JAX profiler's trace while tracing is on.
+- :mod:`repro.obs.host` — after-the-fact spans for what no layer calls:
+  garbage collections (``host.gc``) and JAX compilations
+  (``jax.compile``).
 - :mod:`repro.obs.metrics` — typed counters/gauges/histograms with
   fixed-bucket p50/p99; the components' ``telemetry()`` dicts are thin
   views over it (:class:`StatsView`), byte-identical key sets.
@@ -21,6 +24,7 @@ a sampling rate) and the disabled path is a single contextvar read —
 
 from __future__ import annotations
 
+from . import host
 from .export import (chrome_trace_events, span_dicts, write_chrome_trace,
                      write_spans_jsonl)
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry, StatsView,
@@ -39,48 +43,37 @@ __all__ = [
 
 def configure(enabled: bool | None = None, sample: float | None = None,
               clock=None) -> Tracer:
-    """Configure the global tracer; enabling also installs the engine
-    execute/latency span hooks (idempotent)."""
+    """Configure the global tracer.  Enabling also installs the engine
+    execute/latency metric hooks and the host GC and compile observers
+    (:mod:`repro.obs.host`); disabling removes the host observers."""
     tracer.configure(enabled=enabled, sample=sample, clock=clock)
     if tracer.enabled:
         install_engine_hooks()
+        host.install()
+    else:
+        host.uninstall()
     return tracer
 
 
-# -- engine phase spans via the engine's own observation hooks --------------
+# -- engine metrics via the engine's own observation hooks -------------------
 #
-# The execute hook opens an "engine.execute" span as the pass starts; the
-# latency hook closes it with the engine's own measured elapsed time.  Spans
-# attach to whatever context is current on the executing thread (the
-# service activates the engine-pass span around E.execute), so strategies
-# and the engine's pipeline stay untouched.
+# The engine opens its own spans (core/engine.py); these hooks only feed the
+# operator-facing ``engine/executions`` counter and ``engine/pass_latency_s``
+# histogram.
 
-_pending: dict[int, object] = {}
 _installed = False
 
 
 def _on_execute(plan) -> None:
     get_registry().counter("engine/executions").inc()
-    if not tracer.enabled:
-        return
-    span = tracer.start("engine.execute",
-                        tags={"mode": plan.placement.mode,
-                              "n_specs": len(plan),
-                              "n_groups": len(plan.groups),
-                              "n_dev": plan.placement.n_dev})
-    if span:
-        _pending[id(plan)] = span
 
 
 def _on_latency(plan, elapsed_s: float) -> None:
     get_registry().histogram("engine/pass_latency_s").observe(elapsed_s)
-    span = _pending.pop(id(plan), None)
-    if span is not None:
-        span.finish(end_s=span.span.start_s + elapsed_s)
 
 
 def install_engine_hooks() -> None:
-    """Register the engine execute/latency span hooks (idempotent).
+    """Register the engine execute/latency metric hooks (idempotent).
     Imports the engine lazily so ``repro.obs`` itself stays importable
     without jax."""
     global _installed
@@ -105,5 +98,4 @@ def uninstall_engine_hooks() -> None:
         engine.remove_latency_hook(_on_latency)
     except ValueError:
         pass
-    _pending.clear()
     _installed = False

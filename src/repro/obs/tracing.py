@@ -24,6 +24,14 @@ explicitly — the frontend does this so the ``request.queued`` /
 ``queued_at``/``batched_at``/``served_at`` rather than approximating
 them.
 
+While tracing is enabled, every span used as a context manager also enters
+a ``jax.profiler.TraceAnnotation`` of the same name, so under a running
+profiler it lands on the trace's host plane, on its own thread's line, on
+the device trace's clock.  Spans stamped after the fact (``request.*``
+lifecycle children, :meth:`Tracer.record`, :meth:`Tracer.defer`) cannot be
+mirrored: the profiler only takes annotations as they happen.  JAX is
+imported when tracing is enabled, never at import time.
+
     from repro.obs import tracer
     tracer.configure(enabled=True)
     with tracer.start_trace("request", tags={"key": k}) as root:
@@ -34,6 +42,7 @@ them.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import random
@@ -119,12 +128,13 @@ class SpanHandle:
     """A live span: tag it, finish it, or use it as a context manager
     (which also makes it the current span for code underneath)."""
 
-    __slots__ = ("_tracer", "span", "_token")
+    __slots__ = ("_tracer", "span", "_token", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self.span = span
         self._token = None
+        self._annotation = None
 
     @property
     def context(self) -> SpanContext:
@@ -151,9 +161,13 @@ class SpanHandle:
 
     def __enter__(self) -> "SpanHandle":
         self._token = _current.set(self.span.context)
+        self._annotation = self._tracer.mirror(self.span.name)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -188,6 +202,10 @@ class Tracer:
         self._lock = threading.Lock()
         self._spans: list[Span] = []
         self._counter = 0
+        # spans queued by defer(), recorded at the next spans()/drain()
+        self._deferred: collections.deque = collections.deque()
+        # jax.profiler.TraceAnnotation while enabled, else None
+        self._annotation = None
 
     # -- configuration ----------------------------------------------------
 
@@ -195,7 +213,10 @@ class Tracer:
                   sample: float | None = None,
                   clock=None) -> "Tracer":
         if enabled is not None:
+            if not enabled:
+                self._flush_deferred()
             self._enabled = bool(enabled)
+            self._annotation = _trace_annotation() if enabled else None
         if sample is not None:
             if not 0.0 < sample <= 1.0:
                 raise ValueError(f"sample rate must be in (0, 1], got "
@@ -266,6 +287,43 @@ class Tracer:
         """Alias for :meth:`start` — reads as a context manager."""
         return self.start(name, parent=parent, tags=tags)
 
+    def record(self, name: str, start_s: float, end_s: float,
+               tags: dict | None = None,
+               parent: SpanContext | None = None) -> Span | None:
+        """Record a span stamped after the fact: a child of ``parent``
+        (default: the current span), else a trace root of its own.  Not
+        mirrored into the profiler trace."""
+        if not self._enabled:
+            return None
+        if parent is None:
+            parent = _current.get()
+        handle = (self.start_trace(name, tags=tags, start_s=start_s)
+                  if parent is None else
+                  self.start(name, parent=parent, tags=tags, start_s=start_s))
+        return handle.finish(end_s=end_s) if handle else None
+
+    def defer(self, name: str, start_s: float, end_s: float,
+              tags: dict | None = None,
+              parent: SpanContext | None = None) -> None:
+        """:meth:`record`, postponed to the next :meth:`spans` or
+        :meth:`drain`.  Takes no lock, so it is safe where a lock may
+        already be held on the same thread (a garbage-collector callback
+        runs between any two bytecodes)."""
+        self._deferred.append((name, start_s, end_s, tags, parent))
+
+    def _flush_deferred(self) -> None:
+        while self._deferred:
+            self.record(*self._deferred.popleft())
+
+    def mirror(self, name: str):
+        """Enter a profiler annotation named ``name`` and return it (the
+        caller exits it), or None when tracing is off."""
+        if self._annotation is None:
+            return None
+        annotation = self._annotation(name)
+        annotation.__enter__()
+        return annotation
+
     @contextlib.contextmanager
     def activate(self, ctx: SpanContext | None):
         """Make ``ctx`` the context-local current span for a block — the
@@ -289,19 +347,31 @@ class Tracer:
 
     def spans(self) -> list[Span]:
         """Snapshot of finished spans (oldest first)."""
+        self._flush_deferred()
         with self._lock:
             return list(self._spans)
 
     def drain(self) -> list[Span]:
         """Return finished spans and clear the buffer."""
+        self._flush_deferred()
         with self._lock:
             out = self._spans
             self._spans = []
         return out
 
     def clear(self) -> None:
+        self._deferred.clear()
         with self._lock:
             self._spans = []
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 #: The process-global tracer every instrumented layer talks to.

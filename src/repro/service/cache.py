@@ -173,13 +173,19 @@ class FrontierCache:
         """Insert into every tier.  ``scope`` is the invalidation-scope
         digest map published alongside the shared-registry copy
         (:func:`repro.service.keys.key_scope`); ignored without a
-        registry."""
+        registry.  Runs inside a ``store.put`` span tagged with the
+        ``tiers`` written."""
         self.stats.puts += 1
-        self._insert(key, result)
-        if self.store_dir is not None:
-            self.save_artifact(key, result)
-        if self.registry is not None:
-            self.registry.publish(key, result, scope=scope)
+        with tracer.span("store.put") as span:
+            tiers = ["mem"]
+            self._insert(key, result)
+            if self.store_dir is not None:
+                self.save_artifact(key, result)
+                tiers.append("disk")
+            if self.registry is not None:
+                self.registry.publish(key, result, scope=scope)
+                tiers.append("registry")
+            span.set_tag("tiers", ",".join(tiers))
 
     def _insert(self, key: str, result: SearchResult) -> None:
         self._lru[key] = result

@@ -117,7 +117,7 @@ class _Entry:
     """One queued request plus its scheduling state."""
 
     __slots__ = ("request", "ticket", "on_event", "submitted_at",
-                 "deadline_at", "batched_at", "span")
+                 "deadline_at", "window_at", "batched_at", "span")
 
     def __init__(self, request, ticket, on_event, submitted_at, deadline_at,
                  span=None):
@@ -126,6 +126,7 @@ class _Entry:
         self.on_event = on_event
         self.submitted_at = submitted_at
         self.deadline_at = deadline_at
+        self.window_at = None   # when the batching window it left opened
         self.batched_at = None
         self.span = span        # the request's trace root (SpanHandle|noop)
 
@@ -325,13 +326,18 @@ class ServiceFrontend:
 
     def _collect(self) -> list[_Entry] | None:
         """Block until work exists, hold the batching window open, then
-        drain up to ``max_batch`` entries in (priority, FIFO) order."""
+        drain up to ``max_batch`` entries in (priority, FIFO) order.  The
+        wait for work is traced as a ``frontend.idle`` root: the scheduler
+        has nothing to do until a request arrives."""
         with self._work:
-            while not self._heap and not self._stopping:
-                self._work.wait()
+            if not self._heap and not self._stopping:
+                with tracer.start_trace("frontend.idle"):
+                    while not self._heap and not self._stopping:
+                        self._work.wait()
             if not self._heap:
                 return None                      # stopping and drained
-            deadline = self._clock() + self.effective_window()
+            window_at = self._clock()
+            deadline = window_at + self.effective_window()
             while (len(self._heap) < self.max_batch
                    and not self._stopping):
                 remaining = deadline - self._clock()
@@ -339,6 +345,8 @@ class ServiceFrontend:
                     break
                 self._work.wait(remaining)
             batch = self._pop_batch_locked()
+            for e in batch:
+                e.window_at = window_at
             self._inflight += 1
             return batch
 
@@ -430,6 +438,12 @@ class ServiceFrontend:
                 tracer.start("request.queued", parent=e.span.context,
                              start_s=e.submitted_at
                              ).finish(end_s=e.batched_at)
+                if e.window_at is not None:
+                    # the part of the queued time spent in the batching
+                    # window (the rest waited behind a busy scheduler)
+                    tracer.start("request.window", parent=e.span.context,
+                                 start_s=max(e.submitted_at, e.window_at)
+                                 ).finish(end_s=e.batched_at)
                 tracer.start("request.batched", parent=e.span.context,
                              start_s=e.batched_at,
                              tags={"batch_size": len(live)}

@@ -425,10 +425,11 @@ class SynthesisService:
 
         When tracing is on, the pass runs under an ``engine.pass`` trace of
         its own (a fused pass is shared by N requests, so it cannot live
-        inside any single request's trace) with ``engine.plan`` /
-        ``engine.place`` / ``engine.execute`` / per-lane ``engine.extract``
-        phase children; ``pass_ref`` (when given) is filled with the pass
-        span's ids so the caller can cross-link each request's trace to it.
+        inside any single request's trace) with ``engine.plan`` (tables,
+        placement and grouping) / ``engine.execute`` / per-lane
+        ``engine.extract`` phase children, and a ``store.put`` per result
+        written; ``pass_ref`` (when given) is filled with the pass span's
+        ids so the caller can cross-link each request's trace to it.
         """
         with tracer.start_trace("engine.pass",
                                 tags={"mode": mode,
@@ -442,13 +443,10 @@ class SynthesisService:
                             for r, (_, _, _, cfg) in zip(requests, eff)]
                 tables = [B.SpecTables(r.spec, tech, config=cfg)
                           for r, (tech, _, _, cfg) in zip(requests, eff)]
-            with tracer.span("engine.place") as pspan:
                 placement = E.place(resolve_service_mode(mode,
                                                          len(requests)))
-                pspan.set_tag("mode", placement.mode)
-                pspan.set_tag("n_dev", placement.n_dev)
-            plan = E.plan_for(lattices, tables, placement=placement)
-            evals = E.execute(plan)          # engine.execute span: obs hooks
+                plan = E.plan_for(lattices, tables, placement=placement)
+            evals = E.execute(plan)
             self.stats.fused_passes += 1
             # A sweep lane's frontier and slice records are extracted where
             # the pass ran: the host walk after a one-device pass, the

@@ -23,6 +23,7 @@ import subprocess
 import sys
 import textwrap
 import threading
+import time
 from argparse import Namespace
 from pathlib import Path
 
@@ -54,7 +55,7 @@ def traced():
     configure(enabled=True, sample=1.0)
     tracer.clear()
     yield tracer
-    tracer.configure(enabled=False)
+    configure(enabled=False)
     tracer.clear()
 
 
@@ -405,15 +406,15 @@ class TestServiceSpans:
         # the links live in the REQUESTS' traces, not the pass's own
         assert all(l.trace_id != engine_pass.trace_id for l in links)
         # phase children inside the pass trace
-        for phase in ("engine.plan", "engine.place", "engine.execute"):
+        for phase in ("engine.plan", "engine.execute"):
             (p,) = _by_name(spans, phase)
             assert p.trace_id == engine_pass.trace_id
+        assert not _by_name(spans, "engine.place")
         extracts = _by_name(spans, "engine.extract")
         assert len(extracts) == 2
         execute = _by_name(spans, "engine.execute")[0]
         assert execute.tags["n_specs"] == 2
-        place = _by_name(spans, "engine.place")[0]
-        assert place.tags["mode"] and place.tags["n_dev"] >= 1
+        assert execute.tags["mode"] and execute.tags["n_dev"] >= 1
 
     def test_untraced_serve_records_nothing(self, traced):
         svc = SynthesisService(tech=TECH, resolution=3)
@@ -800,3 +801,187 @@ class TestObsEightDevices:
         assert out["aligned"]
         assert out["passes"] >= 1 and out["linked"]
         assert out["links"] == 6
+
+
+# ---------------------------------------------------------------------------
+# Inside the engine: execute and extract children, host spans, mirroring
+# ---------------------------------------------------------------------------
+
+_EXECUTE_DRILL = textwrap.dedent("""
+    import json, sys
+    from repro.core import calibrated_tech_for_reference, engine
+    from repro.core.shardspec import spec_variants
+    from repro.obs import configure, tracer
+
+    mode = sys.argv[1]
+    configure(enabled=True, sample=1.0)
+    p = engine.plan(spec_variants(3, seed=7), calibrated_tech_for_reference(),
+                    mode=mode)
+    engine.execute(p)                   # compile outside the traced pass
+    tracer.clear()
+    with tracer.start_trace("drill"):
+        engine.execute(p)
+    print(json.dumps([s.as_dict() for s in tracer.drain()]))
+""")
+
+
+class TestEngineChildSpans:
+    @pytest.mark.parametrize("mode,devices", [("vmap", 1),
+                                              ("sharded-jit", 2)])
+    def test_execute_span_parents_its_steps(self, mode, devices):
+        env = {**os.environ, "PYTHONPATH": str(REPO / "src"),
+               "JAX_PLATFORMS": "cpu",
+               "XLA_FLAGS": f"--xla_force_host_platform_device_count="
+                            f"{devices}"}
+        r = subprocess.run([sys.executable, "-c", _EXECUTE_DRILL, mode],
+                           capture_output=True, text=True, env=env,
+                           timeout=600, cwd=REPO)
+        assert r.returncode == 0, r.stderr[-3000:]
+        spans = json.loads(r.stdout.strip().splitlines()[-1])
+        (execute,) = [s for s in spans if s["name"] == "engine.execute"]
+        assert execute["tags"]["n_specs"] == 3
+        assert execute["tags"]["mode"] == mode
+        assert execute["tags"]["n_dev"] == devices
+        assert execute["tags"]["n_groups"] == 1
+        children = [s for s in spans if s["parent_id"] == execute["span_id"]]
+        assert [s["name"] for s in children] == [
+            "execute.pack", "execute.wait", "execute.fetch", "execute.unpack"]
+        for c in children:
+            assert execute["start_s"] <= c["start_s"] <= c["end_s"] \
+                <= execute["end_s"]
+        fetch = children[2]
+        assert fetch["tags"]["arrays"] > 0 and fetch["tags"]["bytes"] > 0
+
+    def test_cold_sweep_records_masks_and_puts(self, traced, tmp_path):
+        from repro.core import subcircuits as sc
+        from repro.core.axes import LatticeConfig
+        cfg = LatticeConfig(memcells=(sc.MemCellKind.SRAM_6T,))
+        svc = SynthesisService(tech=TECH, config=cfg,
+                               cache=FrontierCache(store_dir=tmp_path))
+        (spec,) = spec_variants(1, seed=5)
+        svc.serve([SynthesisRequest(spec=spec, tech=TECH, kind="sweep")])
+        spans = tracer.drain()
+        by_id = {s.span_id: s for s in spans}
+        masks = _by_name(spans, "extract.mask")
+        assert masks
+        for m in masks:
+            assert by_id[m.parent_id].name == "engine.extract"
+            assert m.tags["where"] == "host" and m.tags["rows"] > 0
+        puts = _by_name(spans, "store.put")
+        assert len(puts) == svc.cache.stats.puts > 1
+        assert {p.tags["tiers"] for p in puts} == {"mem,disk"}
+        # the full-sweep result is written after its engine.extract closes
+        (extract,) = _by_name(spans, "engine.extract")
+        assert puts[-1].start_s >= extract.end_s
+        assert by_id[puts[-1].parent_id].name == "engine.pass"
+        # nothing is named under the readers' prefixes
+        assert not [s for s in spans if s.name.startswith("engine.extract.")
+                    or s.name.startswith("cache.put")]
+
+
+class TestFrontendIdleAndWindow:
+    def test_request_window_lies_inside_request_queued(self, traced):
+        svc = SynthesisService(tech=TECH, resolution=3)
+        specs = spec_variants(3, seed=33)
+        with ServiceFrontend(svc, window=0.02, max_batch=8) as front:
+            time.sleep(0.2)              # the scheduler waits for arrivals
+            tickets = [front.submit(SynthesisRequest(spec=s)) for s in specs]
+            for t in tickets:
+                t.result(timeout=600)
+        spans = tracer.drain()
+        queued = {s.parent_id: s for s in _by_name(spans, "request.queued")}
+        windows = _by_name(spans, "request.window")
+        assert len(windows) == 3
+        for w in windows:
+            q = queued[w.parent_id]
+            assert q.start_s <= w.start_s <= w.end_s == q.end_s
+        (first, *_) = sorted(_by_name(spans, "frontend.idle"),
+                             key=lambda s: s.start_s)
+        assert first.parent_id is None
+        # the wait ends only once a request has arrived
+        assert first.end_s >= min(q.start_s for q in queued.values())
+
+
+class TestHostSpans:
+    def test_forced_collection_records_host_gc(self, traced):
+        import gc
+        from repro.obs import host
+        assert host._on_gc in gc.callbacks
+        heap = [{"i": i, "t": (i, str(i))} for i in range(600_000)]
+        gc.collect()
+        del heap
+        spans = tracer.drain()
+        collections = _by_name(spans, "host.gc")
+        assert collections
+        assert all(s.duration_s >= host.GC_MIN_S for s in collections)
+        assert 2 in {s.tags["generation"] for s in collections}
+
+    def test_gc_callbacks_unchanged_while_off(self):
+        import gc
+        configure(enabled=False)
+        before = list(gc.callbacks)
+        heap = [{"i": i} for i in range(100_000)]
+        gc.collect()
+        del heap
+        assert gc.callbacks == before
+        assert tracer.spans() == []
+
+    def test_new_jit_records_jax_compile(self, traced):
+        import random
+        import jax
+        import jax.numpy as jnp
+        c = random.random()              # a program no cache holds
+        with tracer.start_trace("request"):
+            jax.jit(lambda x: x * c + 1.0)(jnp.ones(3)).block_until_ready()
+        spans = tracer.drain()
+        (root,) = _by_name(spans, "request")
+        compiles = _by_name(spans, "jax.compile")
+        assert compiles
+        assert all(s.parent_id == root.span_id for s in compiles)
+        assert all(s.end_s >= s.start_s for s in compiles)
+
+    def test_tracing_off_adds_no_span_callback_or_annotation(self, tmp_path):
+        import gc
+        import jax
+        import jax.numpy as jnp
+        from jax._src import monitoring
+        from repro.obs import host
+        configure(enabled=True)
+        configure(enabled=False)
+        assert host._on_gc not in gc.callbacks
+        assert host._on_duration not in \
+            monitoring.get_event_duration_listeners()
+        assert tracer.mirror("x") is None
+        with tracer.span("x") as s:
+            assert s is NOOP_SPAN
+        svc = SynthesisService(tech=TECH, resolution=3,
+                               cache=FrontierCache(store_dir=tmp_path))
+        with ServiceFrontend(svc, window=0.01) as front:
+            front.serve([SynthesisRequest(spec=spec_variants(1, seed=34)[0])],
+                        timeout=600)
+        jax.jit(lambda x: x - 2.5)(jnp.ones(2)).block_until_ready()
+        gc.collect()
+        assert tracer.spans() == []
+
+    def test_spans_enter_profiler_annotations_while_on(self, traced,
+                                                       monkeypatch):
+        entered = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                entered.append(("exit", self.name))
+
+        monkeypatch.setattr(tracer, "_annotation", Annotation)
+        with tracer.start_trace("request"):
+            with tracer.span("cache.mem"):
+                pass
+        tracer.start("request.queued",
+                     parent=tracer.spans()[0].context).finish()
+        assert entered == [("enter", "request"), ("enter", "cache.mem"),
+                           ("exit", "cache.mem"), ("exit", "request")]
