@@ -54,11 +54,13 @@ span per call with a child per group for each of its steps — ``execute.pack``
 (host operand packing), ``execute.wait`` (device compute and queueing),
 ``execute.fetch`` (device-to-host copy) and ``execute.unpack`` (the numpy
 tail); the rest of ``engine.execute`` is operand upload and launch.
-:func:`extract_frontier` records ``extract.mask`` around each survivor mask.
+:func:`extract_frontier` records ``extract.mask`` around each survivor mask,
+tagged with its row counts.
 """
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Sequence
@@ -78,7 +80,8 @@ from .macro import MacroSpec
 # strategies sizing frontier chunks against the accelerator budget.
 from .pareto import pareto_chunk_size, pareto_indices  # noqa: F401
 from .pareto import (SHARDED_EXTRACT_MIN_POINTS, auto_mask_shards,
-                     nondominated_mask, nondominated_mask_auto)
+                     nondominated_mask, nondominated_mask_auto,
+                     nondominated_mask_counts)
 from .tech import TechModel
 
 # ---------------------------------------------------------------------------
@@ -544,20 +547,23 @@ def execute(p: ExecutionPlan
 # ---------------------------------------------------------------------------
 
 
-def _mask_site(mask_fn, n_rows: int) -> str:
-    """Where ``mask_fn`` computes an ``n_rows``-row mask: ``"host"`` (the
-    host walk) or ``"devices"`` (the sharded map-reduce, or the one-device
-    chunked pass).  The two dispatchers go to the devices at the sharding
-    payoff point."""
+def _host_mask(mask_fn, n_rows: int):
+    """The host walk ``mask_fn`` runs for an ``n_rows``-row mask, as a call
+    that also returns its counts
+    (:func:`repro.core.pareto.nondominated_mask_counts`), or None where
+    ``mask_fn`` computes it on the devices (the sharded map-reduce, or the
+    one-device chunked pass).  The two dispatchers go to the devices at the
+    sharding payoff point."""
     from . import shardspec
     fn = getattr(mask_fn, "func", mask_fn)       # unwrap functools.partial
-    if fn is nondominated_mask:
-        return "host"
-    if fn is nondominated_mask_auto:
-        return "devices" if auto_mask_shards(n_rows) else "host"
-    if fn is shardspec._sharded_extract:
-        return "devices" if n_rows >= SHARDED_EXTRACT_MIN_POINTS else "host"
-    return "devices"
+    keywords = getattr(mask_fn, "keywords", {})
+    if fn is nondominated_mask or (fn is nondominated_mask_auto
+                                   and not auto_mask_shards(n_rows)):
+        return functools.partial(nondominated_mask_counts, **keywords)
+    if fn is shardspec._sharded_extract and \
+            n_rows < SHARDED_EXTRACT_MIN_POINTS:
+        return nondominated_mask_counts
+    return None
 
 
 def extract_frontier(objs, mask_fn: Callable[[np.ndarray], np.ndarray]
@@ -570,13 +576,21 @@ def extract_frontier(objs, mask_fn: Callable[[np.ndarray], np.ndarray]
     :func:`repro.core.pareto.pareto_indices` on the survivors.  Returns
     indices into ``objs`` sorted by objective tuple.  The mask runs inside
     an ``extract.mask`` span tagged with its ``rows`` and ``where`` it ran
-    (``host`` or ``devices``)."""
+    (``host`` or ``devices``); a host mask also tags the ``distinct`` rows
+    left after its duplicate collapse and the ``survivors`` it refined."""
     objs = np.asarray(objs, dtype=np.float64)
     with tracer.span("extract.mask") as span:
+        host = _host_mask(mask_fn, len(objs))
+        if host is None:
+            mask = np.asarray(mask_fn(objs)).astype(bool)
+        else:
+            mask, distinct, refined = host(objs)
         if span:
             span.set_tag("rows", len(objs))
-            span.set_tag("where", _mask_site(mask_fn, len(objs)))
-        mask = np.asarray(mask_fn(objs)).astype(bool)
+            span.set_tag("where", "devices" if host is None else "host")
+            if host is not None:
+                span.set_tag("distinct", distinct)
+                span.set_tag("survivors", refined)
     survivors = np.flatnonzero(mask)
     order = pareto_indices([tuple(o) for o in objs[mask]])
     return [int(survivors[i]) for i in order]

@@ -4,12 +4,14 @@ The MSO searcher emits a *set* of design points; the compiler returns those on
 the Pareto frontier of (power, area, latency) under the throughput constraint,
 "to be finally chosen based on defined PPA preferences or user selection".
 
-Extraction scales in three tiers, all computing the exact same eps-band
-verdicts (bit-identical masks, same output order):
+Extraction runs in one of three places, all computing the exact same
+eps-band verdicts (bit-identical masks, same output order):
 
-  :func:`nondominated_mask`          host numpy, two-phase exact (block-local
-                                     prefilter, then every local survivor is
-                                     refined against *all* rows);
+  :func:`nondominated_mask`          host numpy, exact in three steps:
+                                     collapse bitwise-equal rows, a sorted
+                                     witness prefilter over the distinct
+                                     rows, then every prefilter survivor is
+                                     refined against *all* distinct rows;
   :func:`repro.core.batched.pareto_mask`
                                      the same chunked predicate on one device;
   :func:`nondominated_mask_sharded`  jitted map-reduce across every visible
@@ -74,46 +76,95 @@ def _as_matrix(objs) -> np.ndarray:
     return objs
 
 
-#: Rows per block of the host mask's first (block-local) phase: small blocks
-#: keep its quadratic cost low while still eliminating most dominated rows.
-LOCAL_BLOCK = 128
+#: Rows per block of the host mask's sorted witness walk, and the largest
+#: input it masks with one all-pairs test instead.  Set by measurement on
+#: sweep-sized inputs: smaller blocks pay more numpy calls per row, larger
+#: ones a quadratic self-test that witnesses would mostly have made moot.
+WALK_BLOCK = 512
+
+
+def nondominated_mask_counts(objs, eps: float = PARETO_EPS,
+                             chunk: int = 1024) -> tuple[np.ndarray, int, int]:
+    """:func:`nondominated_mask`, with the two counts that show how far its
+    steps cut the work: ``(mask, distinct, survivors)`` — the rows left after
+    the exact-duplicate collapse and the rows that reach the refinement.  An
+    input of at most :data:`WALK_BLOCK` rows is masked by one all-pairs test,
+    so both counts are its row count."""
+    objs = _as_matrix(objs)
+    n = objs.shape[0]
+    if n <= WALK_BLOCK:
+        return ~chunk_dominated(objs, objs, eps), n, n
+    # 1. Collapse bitwise-equal rows: their verdicts are equal (the predicate
+    #    reads only the two tuples) and they never dominate each other.
+    objs = np.ascontiguousarray(objs)
+    bits = objs.view(np.int64)
+    order = np.lexsort(bits.T)
+    bits = bits[order]
+    head = np.empty(n, dtype=bool)
+    head[0] = True
+    np.any(bits[1:] != bits[:-1], axis=1, out=head[1:])
+    rows = objs[order[head]]
+    group = np.empty(n, dtype=np.intp)
+    group[order] = np.cumsum(head) - 1
+    # 2. Witness prefilter: walk the distinct rows by their min-max
+    #    normalised objective sum, so dominating rows tend to come first.
+    with np.errstate(invalid="ignore", over="ignore"):
+        lo = rows.min(axis=0)
+        span = rows.max(axis=0) - lo
+        span[~(span > 0)] = 1.0                  # constant column
+        walk = np.argsort(((rows - lo) / span).sum(axis=1), kind="stable")
+    keep = np.zeros(rows.shape[0], dtype=bool)
+    witnesses = rows[:0]
+    for start in range(0, walk.size, WALK_BLOCK):
+        idx = walk[start:start + WALK_BLOCK]
+        blk = rows[idx]
+        # Witnesses go in batches of 1, 2, 4, ...: the first few remove most
+        # rows, and the block is compacted after each batch.
+        lo_w, batch = 0, 1
+        while lo_w < len(witnesses) and idx.size:
+            live = ~chunk_dominated(witnesses[lo_w:lo_w + batch], blk, eps)
+            idx, blk = idx[live], blk[live]
+            lo_w, batch = lo_w + batch, 2 * batch
+        if idx.size:
+            live = ~chunk_dominated(blk, blk, eps)
+            keep[idx[live]] = True
+            witnesses = np.concatenate([witnesses, blk[live]])
+    # 3. Refinement against every distinct row: eps-band dominance is not
+    #    transitive, so a survivor's dominator may be a removed row.
+    survivors = np.flatnonzero(keep)
+    for start in range(0, survivors.size, chunk):
+        idx = survivors[start:start + chunk]
+        keep[idx] = ~chunk_dominated(rows, rows[idx], eps)
+    return keep[group], rows.shape[0], survivors.size
 
 
 def nondominated_mask(objs, eps: float = PARETO_EPS,
                       chunk: int = 1024) -> np.ndarray:
     """Boolean non-dominated mask over an (n, k) objective matrix
-    (minimization), vectorized and chunked.  Entry ``i`` is True iff no row
-    dominates row ``i`` under exactly the :func:`dominates` semantics — this
-    is the single dominance predicate :func:`pareto_indices` and the batched
-    engine's ``pareto_mask`` both reduce to.
+    (minimization).  Entry ``i`` is True iff no row dominates row ``i``
+    under exactly the :func:`dominates` semantics — this is the single
+    dominance predicate :func:`pareto_indices` and the batched engine's
+    ``pareto_mask`` both reduce to.
 
-    Runs as an exact pass in three phases: phase 1 tests each
-    :data:`LOCAL_BLOCK`-row block only against itself (a point dominated inside its own block is
-    dominated, full stop — the witness is a real row), phase 2 tests the
-    local survivors against each other (again a real witness), and phase 3
-    refines what is left against *all* rows.  Because eps-band dominance is
-    not transitive, the refinement deliberately compares against every row,
-    not just other survivors; the resulting mask is identical to the naive
-    all-pairs pass at a fraction of the cost (frontiers are small, so few
-    points reach phase 3)."""
-    objs = _as_matrix(objs)
-    n = objs.shape[0]
-    keep = np.ones(n, dtype=bool)
-    if n == 0:
-        return keep
-    for start in range(0, n, LOCAL_BLOCK):
-        blk = objs[start:start + LOCAL_BLOCK]           # (c, k)
-        keep[start:start + blk.shape[0]] = ~chunk_dominated(blk, blk, eps)
-    survivors = np.flatnonzero(keep)
-    peers = objs[survivors]
-    for start in range(0, survivors.size, chunk):
-        idx = survivors[start:start + chunk]
-        keep[idx] = ~chunk_dominated(peers, objs[idx], eps)
-    survivors = np.flatnonzero(keep)
-    for start in range(0, survivors.size, chunk):
-        idx = survivors[start:start + chunk]
-        keep[idx] = ~chunk_dominated(objs, objs[idx], eps)
-    return keep
+    An input of at most :data:`WALK_BLOCK` rows is one all-pairs test.  A
+    larger one is masked exactly in three steps, every comparison through
+    :func:`chunk_dominated`:
+
+      1. *collapse* — rows with bitwise-equal objective tuples get the same
+         verdict, so one ``lexsort`` groups them and steps 2 and 3 run on
+         the distinct rows only (sweep lattices repeat most tuples);
+      2. *sorted witness prefilter* — the distinct rows are walked in
+         :data:`WALK_BLOCK`-row blocks in order of their min-max normalised
+         objective sum; each block is tested against the witnesses accepted
+         so far, then against itself, and its survivors become witnesses.
+         Every row removed here has a real dominating row;
+      3. *refinement* — each prefilter survivor is tested against *all*
+         distinct rows.  Eps-band dominance is not transitive, so this step,
+         not the prefilter, is what makes the mask equal to the naive
+         all-pairs pass.
+
+    ``chunk`` bounds the survivors refined per call."""
+    return nondominated_mask_counts(objs, eps, chunk)[0]
 
 
 #: Default device-memory budget for one Pareto chunk's comparison masks.

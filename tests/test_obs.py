@@ -878,6 +878,27 @@ class TestEngineChildSpans:
         assert not [s for s in spans if s.name.startswith("engine.extract.")
                     or s.name.startswith("cache.put")]
 
+    def test_host_masks_tag_distinct_and_survivors(self, traced, tmp_path):
+        """Each host ``extract.mask`` span counts the rows left after the
+        duplicate collapse and the rows refined, never more than it masked;
+        the full-lattice masks collapse real duplicates."""
+        from repro.core import subcircuits as sc
+        from repro.core.axes import LatticeConfig
+        from repro.core.pareto import WALK_BLOCK
+        cfg = LatticeConfig(memcells=(sc.MemCellKind.SRAM_6T,))
+        svc = SynthesisService(tech=TECH, config=cfg,
+                               cache=FrontierCache(store_dir=tmp_path))
+        (spec,) = spec_variants(1, seed=5)
+        svc.serve([SynthesisRequest(spec=spec, tech=TECH, kind="sweep")])
+        masks = _by_name(tracer.drain(), "extract.mask")
+        assert masks
+        for m in masks:
+            t = m.tags
+            assert 0 <= t["survivors"] <= t["distinct"] <= t["rows"]
+            if t["rows"] <= WALK_BLOCK:
+                assert t["survivors"] == t["distinct"] == t["rows"]
+        assert any(m.tags["distinct"] < m.tags["rows"] for m in masks)
+
 
 class TestFrontendIdleAndWindow:
     def test_request_window_lies_inside_request_queued(self, traced):

@@ -41,8 +41,10 @@ from hypothesis import strategies as st
 from repro.core import calibrated_tech_for_reference, mso_search
 from repro.core.macro import MacroSpec
 from repro.core.multispec import mso_search_many, scenario_specs
-from repro.core.pareto import (LOCAL_BLOCK, PARETO_EPS, dominates,
-                               nondominated_mask, nondominated_mask_sharded)
+from repro.core.pareto import (PARETO_EPS, WALK_BLOCK, chunk_dominated,
+                               dominates, nondominated_mask,
+                               nondominated_mask_counts,
+                               nondominated_mask_sharded)
 from repro.core.shardspec import (mso_search_many_sharded, resolve_mode,
                                   spec_variants)
 
@@ -316,6 +318,91 @@ def _extraction_points(seed: int, n: int, k: int = 3) -> np.ndarray:
     return objs
 
 
+def _repeated_rows(_tech) -> np.ndarray:
+    """Each row repeated 1-8 times, shuffled: most rows are exact copies."""
+    rng = np.random.default_rng(3)
+    base = _extraction_points(3, 300, 3)
+    objs = np.repeat(base, rng.integers(1, 9, size=len(base)), axis=0)
+    return objs[rng.permutation(len(objs))]
+
+
+def _eps_chains(_tech) -> np.ndarray:
+    """Chains a > b > c under the eps band in which a does not dominate c.
+    The a rows walk first (lowest sum) and remove every b; the c rows walk
+    last (the third column's span is 2 eps), so no b is left to remove them
+    in the prefilter: only the refinement against every row finds their
+    dominator."""
+    e, n = PARETO_EPS, 600
+    x = np.arange(n) / n
+    b = np.stack([x, 1.0 - x, np.zeros(n)], axis=1)     # an anti-chain
+    a = b + np.array([-0.5 / n, 0.6 * e, 0.0])
+    c = b + np.array([0.0, -0.6 * e, 2 * e])
+    objs = np.concatenate([a, b, c])
+    return objs[np.random.default_rng(4).permutation(len(objs))]
+
+
+def _shared_columns(_tech) -> np.ndarray:
+    """Rows on a coarse grid, as a sweep's objectives are: many rows share
+    one or two coordinates exactly without being duplicates, so a collapse
+    that grouped on part of a row would merge different verdicts."""
+    rng = np.random.default_rng(11)
+    objs = np.stack([rng.integers(0, 4, 1500), rng.integers(0, 41, 1500),
+                     rng.integers(0, 41, 1500)], axis=1)
+    return objs * np.array([0.5, 0.1, 0.3])
+
+
+def _constant_column(_tech) -> np.ndarray:
+    """A column with zero span: the walk's normalisation must not divide by
+    it."""
+    objs = _extraction_points(5, 2 * WALK_BLOCK + 3, 3)
+    objs[:, 1] = 0.25
+    return objs
+
+
+def _non_finite(_tech) -> np.ndarray:
+    """Infinite and NaN objectives: they only reorder the walk."""
+    objs = _extraction_points(6, 2 * WALK_BLOCK, 3)
+    objs[::97, 0] = np.inf
+    objs[5::89, 2] = -np.inf
+    objs[7::101, 1] = np.nan
+    return objs
+
+
+def _sweep_slice(tech) -> np.ndarray:
+    """One memcell, precision plan and adder-tree cell of a syndcim-full
+    sweep (every valid point of that slice, 5,760 rows)."""
+    from repro.core import subcircuits as sc
+    from repro.core.axes import LatticeConfig
+    from repro.core.batched import design_space_sweep
+    spec = MacroSpec(h=64, w=64, mcr=2, int_precisions=(4, 8),
+                     fp_precisions=("FP4", "FP8"), f_mac_hz=700e6,
+                     f_wupdate_hz=700e6, vdd=0.9)
+    sweep = design_space_sweep(spec, tech, config=LatticeConfig(
+        precision_modes=3, approx_cells=sc.APPROX_CELLS))
+    lat = sweep.lattice
+    sel = lat.valid.copy()
+    for axis in ("memcell", "precision", "approx_cell"):
+        sel &= lat.coords[lat.axis_pos(axis)] == 0
+    objs = sweep.objectives()[sel]
+    assert len(objs) >= 5000
+    return objs
+
+
+_HOST_MASK_CASES = {
+    "repeated_rows": _repeated_rows,
+    "eps_chains": _eps_chains,
+    "shared_columns": _shared_columns,
+    "constant_column": _constant_column,
+    "non_finite": _non_finite,
+    "one_column": lambda _t: _extraction_points(7, WALK_BLOCK + 40, 1)[:, 0],
+    "n0": lambda _t: np.empty((0, 3)),
+    "n1": lambda _t: _extraction_points(8, 1, 3),
+    "n_block": lambda _t: _extraction_points(9, WALK_BLOCK, 3),
+    "n_block_plus_1": lambda _t: _extraction_points(10, WALK_BLOCK + 1, 3),
+    "sweep_slice": _sweep_slice,
+}
+
+
 class TestShardedParetoExtraction:
     """``nondominated_mask_sharded`` must return bit-identical frontier
     membership and output order vs the host ``nondominated_mask`` — on 1
@@ -335,11 +422,28 @@ class TestShardedParetoExtraction:
 
     @pytest.mark.parametrize("seed", [0, 1])
     def test_host_mask_matches_all_pairs(self, seed):
-        """The host mask's block-local, survivor and full-refinement phases
-        give the naive all-pairs verdicts over several LOCAL_BLOCK blocks."""
-        objs = _extraction_points(seed, 3 * LOCAL_BLOCK + 5, 3)
+        """The host mask's collapse, witness walk and refinement give the
+        naive all-pairs verdicts over several WALK_BLOCK blocks."""
+        objs = _extraction_points(seed, 3 * WALK_BLOCK + 5, 3)
         naive = [not any(dominates(o, p) for o in objs) for p in objs]
         assert nondominated_mask(objs).tolist() == naive
+
+    @pytest.mark.parametrize("case", sorted(_HOST_MASK_CASES))
+    def test_host_mask_gives_all_pairs_verdicts(self, case, tech):
+        """The host mask equals the naive all-pairs pass of the one
+        predicate on inputs built to break each of its steps: duplicates for
+        the collapse, a zero-span column for the walk order, non-transitive
+        eps chains for the refinement, and the one-block boundary."""
+        given = _HOST_MASK_CASES[case](tech)
+        objs = given[:, None] if given.ndim == 1 else given
+        naive = np.ones(len(objs), dtype=bool)
+        for start in range(0, len(objs), 512):
+            naive[start:start + 512] = ~chunk_dominated(
+                objs, objs[start:start + 512], PARETO_EPS)
+        mask, distinct, survivors = nondominated_mask_counts(given)
+        assert mask.tolist() == naive.tolist()
+        assert np.array_equal(nondominated_mask(given), mask)
+        assert survivors <= distinct <= len(objs)
 
     @settings(max_examples=4, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=10**6),
