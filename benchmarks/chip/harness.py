@@ -22,6 +22,15 @@ the program's tracing off; with ``--trace 1`` the window runs under the
 program's spans and the JAX profiler, and the metrics are the cell's
 per-layer metrics (one reader each, ``metrics/<name>.py``).
 
+Set-up, by traffic (``traffic.py``): every macro family of the spec space
+has its roll-up programs warmed at every lane count a pass of the traffic
+can give its group; an open loop serves one spec of each family and its hot
+pool; a closed loop with ``recal`` serves its pool and one spec beside it as
+cold sweeps, then that spec under ``warm_factor``, which compiles the
+incremental re-synthesis path.  Each request counts its own spec's lattice
+points, and is judged under its own tech: the reference's calibration with
+the request's recalibrated field scaled by the same factor.
+
 The run refuses (exit 2, no result) when JAX finds no TPU or fewer chips than
 the cell asks for.  ``--fault`` breaks the timed path on purpose, for the
 correctness controls (``faults.py``); benchmark runs never pass it.
@@ -106,8 +115,32 @@ def check_device(chips: int) -> None:
 # ---------------------------------------------------------------------------
 
 
+def lattice_points(cfg: dict, families: list[dict]) -> dict:
+    """Lattice points of one spec of each family, by ``(h, w)``."""
+    from reference import lattice as L
+    from reference import macro as RM
+    lattice = L.Lattice(int(cfg["lattice"]["precision_modes"]),
+                        bool(cfg["lattice"]["approx_cells"]))
+    return {(f["h"], f["w"]): lattice.size(RM.MacroSpec(h=f["h"], w=f["w"]))
+            for f in families}
+
+
+def batching(traffic: Traffic, chips: int) -> tuple[int, list[int]]:
+    """The frontend's batch limit and the lane counts a fused pass can give
+    one family's group: a closed loop's pass holds one request per client,
+    an open loop's any count up to the limit; where families mix, a pass
+    splits into groups of any count up to the limit."""
+    if traffic.loop == "closed":
+        max_batch = traffic.clients(chips)
+        if len(traffic.families) == 1:
+            return max_batch, [max_batch]
+    else:
+        max_batch = int(traffic.traffic["frontend"]["max_batch"])
+    return max_batch, list(range(1, max_batch + 1))
+
+
 def open_loop(front, dep, arrivals, t0: float, seconds: float,
-              points: int) -> list[Request]:
+              points: dict) -> list[Request]:
     """Submit each arrival at its due time, whatever the system's state."""
     out = []
     for a in arrivals:
@@ -115,7 +148,8 @@ def open_loop(front, dep, arrivals, t0: float, seconds: float,
         wait = due - time.monotonic()
         if wait > 0:
             time.sleep(wait)
-        r = Request(spec=a.spec, due=due, points=points)
+        r = Request(spec=a.spec, due=due,
+                    points=points[a.spec["h"], a.spec["w"]])
         r.submitted = time.monotonic()
         r.ticket = front.submit(dep.request(a.spec))
         out.append(r)
@@ -150,19 +184,25 @@ def collect(requests: list[Request], deadline: float) -> None:
 
 
 def closed_loop(front, dep, traffic: Traffic, clients: int, t0: float,
-                seconds: float, points: int) -> list[Request]:
+                seconds: float, points: dict) -> list[Request]:
     """``clients`` clients, each submitting a fresh request as soon as its
     last one is answered, until the window closes."""
     from repro.service.requests import RequestState
     done: queue.Queue = queue.Queue()
     out: list[Request] = []
     t_end = t0 + seconds
-    stream = traffic.closed_stream(clients)
+    stream = traffic.stream(clients)
 
     def submit():
-        spec = next(stream)
-        r = Request(spec=spec, due=time.monotonic(), points=points)
+        try:
+            spec, recal = next(stream)
+        except StopIteration:
+            raise RuntimeError("the traffic's request stream ran out inside "
+                               "the window") from None
+        r = Request(spec=spec, due=time.monotonic(),
+                    points=points[spec["h"], spec["w"]], recal=recal)
         r.submitted = r.due
+        tech = None if recal is None else dep.recalibrated(*recal)
 
         def on_event(ev, r=r):
             if ev.kind in (RequestState.SERVED.value,
@@ -172,7 +212,7 @@ def closed_loop(front, dep, traffic: Traffic, clients: int, t0: float,
                           or time.monotonic())
                 done.put(r)
 
-        r.ticket = front.submit(dep.request(spec), on_event=on_event)
+        r.ticket = front.submit(dep.request(spec, tech), on_event=on_event)
         out.append(r)
 
     for _ in range(clients):
@@ -199,9 +239,14 @@ def judge(dep_cfg: dict, run: Run, seed: int) -> compare.Comparison:
     """Served answers of the window against the plain reference."""
     from reference import lattice as L
     from reference import macro as RM
+    from reference.tech import recalibrated
     import traffic as T
 
-    tech = getattr(RM, dep_cfg["tech"])()
+    base = getattr(RM, dep_cfg["tech"])()
+
+    def tech_of(r: Request):
+        return base if r.recal is None else recalibrated(base, *r.recal)
+
     lat = dep_cfg["lattice"]
     lattice = L.Lattice(int(lat["precision_modes"]),
                         bool(lat["approx_cells"]))
@@ -215,23 +260,23 @@ def judge(dep_cfg: dict, run: Run, seed: int) -> compare.Comparison:
         memo: dict = {}
         for i in sorted(int(k) for k in pick):
             r = served[i]
-            key = tuple(sorted(r.spec.items()))
+            key = (tuple(sorted(r.spec.items())), r.recal)
             if key not in memo:
-                memo[key] = L.search(RM.MacroSpec(**r.spec), tech,
+                memo[key] = L.search(RM.MacroSpec(**r.spec), tech_of(r),
                                      resolution)
             cmp.add_answer(f"search {r.spec}", r.response.result, memo[key])
         return cmp
     # sweeps: every served point against its own roll-up, and whole sweeps
     for r in served:
-        spec = RM.MacroSpec(**r.spec)
+        spec, tech = RM.MacroSpec(**r.spec), tech_of(r)
         for p in r.response.result.frontier + r.response.result.explored:
             cmp.add_point(p, RM.rollup(compare.reference_design(
                 p.design, spec), tech))
     pick = gen.permutation(len(served))[:SWEEP_SAMPLE]
     for i in sorted(int(k) for k in pick):
         r = served[i]
-        cmp.add_answer(f"sweep {r.spec}", r.response.result,
-                       L.sweep(RM.MacroSpec(**r.spec), tech, lattice,
+        cmp.add_answer(f"sweep {r.spec} {r.recal or ''}", r.response.result,
+                       L.sweep(RM.MacroSpec(**r.spec), tech_of(r), lattice,
                                workers=REFERENCE_WORKERS))
     return cmp
 
@@ -267,32 +312,30 @@ def run(argv=None, *, require_chip: bool = True, root: Path = ROOT) -> int:
 
     dep = sut.Deployment(cfg)
     traffic = Traffic(cfg, trf, args.seed)
-    from reference import lattice as L
-    from reference import macro as RM
-    points = L.Lattice(int(cfg["lattice"]["precision_modes"]),
-                       bool(cfg["lattice"]["approx_cells"])
-                       ).size(RM.MacroSpec(h=cfg["spec_space"]["h"],
-                                           w=cfg["spec_space"]["w"]))
+    points = lattice_points(cfg, traffic.families)
     fe = trf["frontend"]
-    if traffic.loop == "closed":
-        max_batch = traffic.clients(chips)
-        lane_counts = [max_batch]
-    else:
-        max_batch = int(fe["max_batch"])
-        lane_counts = list(range(1, max_batch + 1))
+    max_batch, lane_counts = batching(traffic, chips)
 
-    # -- set-up: warm the cell's programs, serve the hot pool ---------------
+    # -- set-up: warm the cell's programs, serve the first requests ----------
     marks = [("start-up", time.monotonic())]
-    dep.warm_engine(traffic.warm_specs(max(lane_counts)), lane_counts)
+    for k in range(len(traffic.families)):
+        dep.warm_engine(traffic.warm_specs(max(lane_counts), k), lane_counts,
+                        mixed=len(traffic.families) > 1)
     marks.append(("roll-up programs", time.monotonic()))
-    buckets = dep.warm_sharded_mask(points)
+    buckets = dep.warm_sharded_mask(max(points.values()))
     marks.append(("sharded mask", time.monotonic()))
     store = tempfile.mkdtemp(prefix="bench-artifacts-")
     service = dep.service(store)
     front = dep.frontend(service, fe, max_batch)
     if traffic.loop == "open":
-        warm = [traffic.warm_spec] + traffic.pool
+        warm = traffic.warm + traffic.pool
         front.serve([dep.request(s) for s in warm], timeout=600)
+    elif traffic.recal is not None:
+        pool, beside = traffic.recal_specs()
+        front.serve([dep.request(s) for s in pool + [beside]], timeout=600)
+        tech = dep.recalibrated(traffic.recal["field"],
+                                traffic.recal["warm_factor"])
+        front.serve([dep.request(beside, tech)], timeout=600)
     marks.append(("first requests and pool", time.monotonic()))
     log("set-up: " + ", ".join(
         f"{name} {t - prev:.3f} s" for (name, t), (_, prev)
@@ -300,6 +343,7 @@ def run(argv=None, *, require_chip: bool = True, root: Path = ROOT) -> int:
     arrivals = (traffic.schedule(args.seconds)
                 if traffic.loop == "open" else None)
     compiles = sut.compile_counter()
+    stats0 = service.stats.as_dict()
 
     # -- the window ------------------------------------------------------------
     trace_dir = None
@@ -333,6 +377,7 @@ def run(argv=None, *, require_chip: bool = True, root: Path = ROOT) -> int:
     fstats = front.stats.as_dict()
     front.close(drain=False)
     collect([r for r in requests if r.response is None], time.monotonic())
+    sstats = service.stats.as_dict()
     spans = obs.tracer.drain() if args.trace else []
     obs.configure(enabled=False)
     device = sut.device_info(chips)
@@ -340,7 +385,10 @@ def run(argv=None, *, require_chip: bool = True, root: Path = ROOT) -> int:
     log(f"window: {len(requests)} requests, generator late by at most "
         f"{max(lateness, default=0.0):.6f} s (mean "
         f"{sum(lateness) / max(1, len(lateness)):.6f} s)")
-    log(f"frontend: {fstats}; service: {service.stats.as_dict()}")
+    log(f"frontend: {fstats}; service: {sstats}")
+    log(f"service in the window: "
+        f"{ {k: v - stats0[k] for k, v in sstats.items()} }; "
+        f"{sum(r.served for r in requests)} of its requests served")
     if traffic.loop == "open":
         due = latency_halves(requests, t0, t_end, gave_up)
         log(f"latency p50 of the window's first / second half: {due}")
@@ -350,6 +398,12 @@ def run(argv=None, *, require_chip: bool = True, root: Path = ROOT) -> int:
 
     result_run = Run(t0=t0, t_end=t_end, setup_s=setup_s, gave_up=gave_up,
                      requests=requests, spans=spans)
+    executes = result_run.window_spans("engine.execute")
+    if executes:
+        log(f"engine in the window: {len(executes)} passes, "
+            f"{result_run.lanes()} lanes, at most "
+            f"{max(int(s.tags.get('n_groups', 0)) for s in executes)} "
+            "groups in a pass")
     breakdown = None
     if args.trace:
         dig = tracefile.digest(tracefile.find_xplane(trace_dir))

@@ -219,3 +219,9 @@ def calibrated_tech(reference_crit_rel: float | None = None,
         apr = AREA_ANCHOR_UM2 / reference_area_um2
 
     return base.with_calibration(tau_ps=tau, eps_fj=eps, apr_overhead=apr)
+
+
+def recalibrated(tech: TechModel, field: str, factor: float) -> TechModel:
+    """``tech`` with one field multiplied by ``factor``: a recharacterized
+    cell (a new PDK drop) in an otherwise unchanged calibration."""
+    return dataclasses.replace(tech, **{field: getattr(tech, field) * factor})

@@ -25,6 +25,7 @@ class Request:
     done: float | None = None  # response stamp; None if it never came
     response: object = None    # SynthesisResponse | SheddedResponse | None
     points: int = 0            # lattice points the request covers
+    recal: tuple | None = None     # (tech field, factor) it was asked under
     ticket: object = None      # the frontend's handle on it
 
     @property

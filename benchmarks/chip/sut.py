@@ -2,11 +2,19 @@
 synthesis service (``repro.service``) behind its frontend, the lattice it
 serves, and the warm-up of exactly the programs the cell's traffic runs.
 
+A request may carry a technology of its own (a recalibrated tech field,
+``recalibrated``), which the service keys and serves per request.  Warm-up
+runs once per macro family of the spec space: a pass that mixes families is
+split by the engine into one group per family, so each family's group sizes
+are warmed on their own.
+
 This is the benchmark's only door into the program; everything it takes from
 there is the served path itself and its spans and counters.
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import numpy as np
 
@@ -35,12 +43,19 @@ class Deployment:
         from repro.core.macro import MacroSpec
         return MacroSpec(**fields)
 
-    def request(self, fields: dict):
+    def request(self, fields: dict, tech=None):
+        """The typed request for one spec, under ``tech`` where given, else
+        under the service's own tech."""
         from repro.service import SynthesisRequest
         return SynthesisRequest(spec=self.spec(fields), kind=self.kind,
-                                config=self.lattice,
+                                tech=tech, config=self.lattice,
                                 resolution=self.resolution,
                                 priority=self.priority)
+
+    def recalibrated(self, field: str, factor: float):
+        """The deployment's tech with one field multiplied by ``factor``."""
+        return dataclasses.replace(
+            self.tech, **{field: getattr(self.tech, field) * factor})
 
     def service(self, store_dir):
         from repro.service import FrontierCache, SynthesisService
@@ -59,11 +74,15 @@ class Deployment:
 
     # -- warm-up ------------------------------------------------------------
 
-    def warm_engine(self, spec_fields: list[dict], lane_counts) -> None:
-        """One ``engine.execute`` per lane count, placed as the service
-        places a fused pass of that many lanes: every roll-up program the
-        window can run is compiled (or loaded from the compile cache) here.
-        The Pareto extraction of a sweep is not run."""
+    def warm_engine(self, spec_fields: list[dict], lane_counts,
+                    mixed: bool = False) -> None:
+        """One ``engine.execute`` per lane count of one family's specs,
+        placed as the service places a fused pass of that many lanes: every
+        roll-up program the window can run is compiled (or loaded from the
+        compile cache) here.  With ``mixed`` a group of n lanes can sit in
+        any pass of n lanes or more, so it is run under the placement of
+        each such pass size.  The Pareto extraction of a sweep is not
+        run."""
         from repro.core import batched as B
         from repro.core import engine as E
         from repro.service.service import resolve_service_mode
@@ -74,9 +93,11 @@ class Deployment:
         tables = [B.SpecTables(s, self.tech, config=self.lattice)
                   for s in specs]
         for n in lane_counts:
-            placement = E.place(resolve_service_mode(self.mode, n))
-            E.execute(E.plan_for(lattices[:n], tables[:n],
-                                 placement=placement))
+            sizes = [m for m in lane_counts if m >= n] if mixed else [n]
+            for mode in dict.fromkeys(resolve_service_mode(self.mode, m)
+                                      for m in sizes):
+                E.execute(E.plan_for(lattices[:n], tables[:n],
+                                     placement=E.place(mode)))
 
     def warm_sharded_mask(self, points: int) -> list[int]:
         """On several devices a sweep's frontiers are extracted by the

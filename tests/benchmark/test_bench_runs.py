@@ -36,11 +36,32 @@ def _tiny_config(kind: str) -> dict:
     return cfg
 
 
+#: Two macro families that the engine cannot fuse into one group: their
+#: split axes and their precision-mode counts differ.
+TWO_FAMILIES = [
+    {"h": 64, "w": 64, "int_precisions": [4, 8],
+     "fp_precisions": ["FP4", "FP8"], "share": 0.5},
+    {"h": 32, "w": 64, "int_precisions": [8], "fp_precisions": ["FP8"],
+     "share": 0.5}]
+
+
+def _two_family_config() -> dict:
+    cfg = _tiny_config("search")
+    cfg["name"] = "tiny-mixed"
+    space = cfg["spec_space"]
+    for k in ("h", "w", "int_precisions", "fp_precisions"):
+        del space[k]
+    space["families"] = TWO_FAMILIES
+    return cfg
+
+
 @pytest.fixture(scope="module")
 def checkout(tmp_path_factory):
-    """A checkout holding the benchmark, the program and three tiny cells:
-    searches on the seed lattice in an open loop of 4-lane batches, and
-    seed-lattice sweeps in a closed loop on one and on two devices."""
+    """A checkout holding the benchmark, the program and five tiny cells:
+    searches on the seed lattice in an open loop of 4-lane batches, the
+    same over two macro families, seed-lattice sweeps in a closed loop on
+    one and on two devices, and the same sweeps re-asked under a
+    recalibrated tech."""
     root = tmp_path_factory.mktemp("checkout")
     shutil.copytree(BENCH, root / "benchmarks" / "chip")
     (root / "src").symlink_to(REPO / "src")
@@ -48,6 +69,8 @@ def checkout(tmp_path_factory):
     for kind in ("search", "sweep"):
         (here / "configs" / f"tiny-{kind}.json").write_text(
             json.dumps(_tiny_config(kind)))
+    (here / "configs" / "tiny-mixed.json").write_text(
+        json.dumps(_two_family_config()))
     open_mix = json.loads((here / "traffic" / "open-miss.json").read_text())
     open_mix["rate_per_s"] = 20.0
     open_mix["frontend"]["max_batch"] = 4
@@ -55,8 +78,12 @@ def checkout(tmp_path_factory):
     closed = json.loads((here / "traffic" / "bulk-closed.json").read_text())
     closed["clients"] = {"1": 2, "2": 4}
     (here / "traffic" / "tiny-closed.json").write_text(json.dumps(closed))
+    recal = json.loads((here / "traffic" / "recal-closed.json").read_text())
+    recal["clients"] = {"1": 2}
+    recal["recal"]["pool_size"] = 4
+    (here / "traffic" / "tiny-recal.json").write_text(json.dumps(recal))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    for kind in ("search", "sweep"):
+    for kind in ("search", "sweep", "mixed"):
         bench["configs"].append({
             "name": f"tiny-{kind}", "source": "test", "reduced": [],
             "file": f"benchmarks/chip/configs/tiny-{kind}.json",
@@ -67,13 +94,19 @@ def checkout(tmp_path_factory):
         {"name": "tiny.sweep", "config": "tiny-sweep",
          "traffic": "tiny-closed", "chips": 1, "why": "test"},
         {"name": "tiny.sweep-x2", "config": "tiny-sweep",
-         "traffic": "tiny-closed", "chips": 2, "why": "test"}]
+         "traffic": "tiny-closed", "chips": 2, "why": "test"},
+        {"name": "tiny.mixed", "config": "tiny-mixed",
+         "traffic": "tiny-open", "chips": 1, "why": "test"},
+        {"name": "tiny.recal", "config": "tiny-sweep",
+         "traffic": "tiny-recal", "chips": 1, "why": "test"}]
     for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m:
-            kind = "sweep" if any("sweep" in w for w in m["workloads"]) \
-                else "search"
-            m["workloads"] += ([f"tiny.{kind}"] if kind == "search"
-                               else ["tiny.sweep", "tiny.sweep-x2"])
+        cells = m.get("workloads", [])
+        if "sweep-full.recal" in cells:
+            cells.append("tiny.recal")
+        if "sweep-full.bulk" in cells:
+            cells += ["tiny.sweep", "tiny.sweep-x2"]
+        if "search-seed.open" in cells:
+            cells += ["tiny.search", "tiny.mixed"]
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root
 

@@ -120,7 +120,7 @@ def test_open_miss_specs_never_repeat():
     t = Traffic(cfg, trf, 99)
     arrivals = t.schedule(30.0)
     keys = [tuple(sorted(a.spec.items())) for a in arrivals]
-    keys.append(tuple(sorted(t.warm_spec.items())))
+    keys += [tuple(sorted(s.items())) for s in t.warm]
     assert len(set(keys)) == len(keys)
     assert not any(a.hot for a in arrivals)
 
